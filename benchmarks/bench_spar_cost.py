@@ -96,8 +96,7 @@ def tune_matvec_block(n: int, ratio: int):
         lambda blk: spar_matvec(Lmat, t, block=blk), reps=reps,
         flops_per_call=2.0 * s * s,
         bytes_per_call=4.0 * s * s)
-    if best is not None:
-        record(f"spar_cost/autotune/n{n}/s{ratio}n", 0.0, f"block={best}")
+    record(f"spar_cost/autotune/n{n}/s{ratio}n", 0.0, f"block={best}")
     path = dispatch.dump_autotune_records()
     if path is not None:
         record("spar_cost/autotune/dump", 0.0, str(path))

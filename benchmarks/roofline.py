@@ -1,9 +1,9 @@
 """Roofline analysis from dry-run artifacts (EXPERIMENTS.md §Roofline).
 
-Per (arch × shape × mesh) cell:
-  compute term    = FLOPs_per_device / peak_FLOP/s        (197 TF bf16, v5e)
-  memory term     = bytes_per_device / HBM_bw             (819 GB/s)
-  collective term = wire_bytes_per_device / ICI_bw        (50 GB/s/link;
+Per (arch × shape × mesh) cell, with the peaks of the record's device:
+  compute term    = FLOPs_per_device / peak_FLOP/s
+  memory term     = bytes_per_device / HBM_bw
+  collective term = wire_bytes_per_device / ICI_bw        (per link;
                     HLO is the per-device program, so per-device wire bytes
                     over per-chip link bw == global_bytes/(chips·link_bw))
 plus MODEL_FLOPS = 6·N·D (train) / 2·N·D (fwd) vs compiled FLOPs.
@@ -12,10 +12,31 @@ from __future__ import annotations
 
 import json
 from pathlib import Path
+from typing import NamedTuple
 
-PEAK_FLOPS = 197e12          # bf16 per chip
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW = 50e9                # bytes/s per link
+
+class Peaks(NamedTuple):
+    flops: float             # bf16 FLOP/s per chip
+    hbm_bw: float            # bytes/s per chip
+    ici_bw: float            # bytes/s per chip-to-chip link
+
+
+# keyed by ``jax.devices()[0].device_kind``. TPU v5e: Google Cloud
+# documentation, "TPU v5e" (cloud.google.com/tpu/docs/v5e) — 197 TFLOP/s
+# bf16, 819 GB/s HBM, 1,600 Gbit/s interchip interconnect over 4 links.
+PEAKS = {
+    "TPU v5 lite": Peaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+
+def peaks(device_kind: str) -> Peaks:
+    """Published peaks of one chip; a device not in the table is an error,
+    never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 ART = Path(__file__).resolve().parents[1] / "artifacts" / "dryrun"
 AUTOTUNE_ART = Path(__file__).resolve().parents[1] / "artifacts" / "autotune"
@@ -58,10 +79,11 @@ def analyze(rec):
     chips = 1
     for v in rec["mesh_shape"].values():
         chips *= v
-    t_comp = rec["flops_per_device"] / PEAK_FLOPS
-    t_mem = rec["bytes_per_device"] / HBM_BW
+    pk = peaks(rec.get("device_kind", "unrecorded"))
+    t_comp = rec["flops_per_device"] / pk.flops
+    t_mem = rec["bytes_per_device"] / pk.hbm_bw
     wire = sum(v["wire_bytes"] for v in rec["collectives"].values())
-    t_coll = wire / ICI_BW
+    t_coll = wire / pk.ici_bw
     dom = max((("compute", t_comp), ("memory", t_mem),
                ("collective", t_coll)), key=lambda kv: kv[1])[0]
     mf = model_flops(rec)

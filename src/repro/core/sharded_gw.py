@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.core import ground_cost as gc
 
@@ -141,6 +141,6 @@ def make_sharded_grid_gw(mesh: Mesh, s_r: int, s_c: int, loss: str = "l2",
         in_specs=(P("data", None), P("model", None), P("data"), P("model"),
                   P("data", "model")),
         out_specs=(P(), P("data", "model")),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(sharded)

@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def pipeline_forward(mesh: Mesh, stage_fn, n_stages: int, n_micro: int):
@@ -71,7 +71,7 @@ def pipeline_forward(mesh: Mesh, stage_fn, n_stages: int, n_micro: int):
             per_stage, mesh=mesh,
             in_specs=(P("pipe"), P()),       # params split by stage; x replicated
             out_specs=P(),                    # outputs replicated (from last stage)
-            check_rep=False,
+            check_vma=False,
         )(stage_params, x)
 
     return pipelined

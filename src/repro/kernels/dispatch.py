@@ -188,13 +188,14 @@ _AUTOTUNE_RECORDS: list[dict] = []
 def autotune(family: str, candidates: Iterable[int],
              bench_fn: Callable[[int], object], reps: int = 3,
              flops_per_call: Optional[float] = None,
-             bytes_per_call: Optional[float] = None) -> Optional[int]:
+             bytes_per_call: Optional[float] = None) -> int:
     """Time ``bench_fn(block)`` over candidate block sizes; cache the best.
 
     The winner feeds subsequent :func:`block_size` resolutions for
     ``family`` (below any explicit/env override) and is appended to the
     in-process record list that ``benchmarks/roofline.py`` reports.
-    Candidates that raise are skipped (e.g. blocks over the VMEM budget).
+    Candidates that raise are skipped (e.g. blocks over the VMEM budget);
+    when every candidate raises, so does this, with the last error chained.
 
     ``flops_per_call`` / ``bytes_per_call`` (caller-supplied analytic
     counts for one ``bench_fn`` invocation) turn the winner's timing into
@@ -202,6 +203,7 @@ def autotune(family: str, candidates: Iterable[int],
     exported as ``repro_autotune_*`` gauges for roofline placement.
     """
     timings: dict[int, float] = {}
+    last_error: Optional[Exception] = None
     for cand in candidates:
         try:
             jax.block_until_ready(bench_fn(cand))        # compile + warm
@@ -209,10 +211,12 @@ def autotune(family: str, candidates: Iterable[int],
             for _ in range(reps):
                 jax.block_until_ready(bench_fn(cand))
             timings[int(cand)] = (time.perf_counter() - t0) / reps
-        except Exception:  # noqa: BLE001 — invalid candidate, keep sweeping
-            continue
+        except Exception as e:  # noqa: BLE001 — invalid candidate, sweep on
+            last_error = e
     if not timings:
-        return None
+        raise RuntimeError(
+            f"autotune({family!r}): every candidate block failed") \
+            from last_error
     best = min(timings, key=timings.get)
     best_s = timings[best]
     _AUTOTUNE_CACHE[family] = best

@@ -5,8 +5,9 @@ Three interchangeable implementations of the affine contract
 
 - ``"jnp"``          — row-chunked ``lax.map`` oracle (ref.py). Gathers the
                        (chunk, s) support blocks from HBM every call.
-- ``"pallas"``       — gather-fused Pallas kernel; O(s·(m+n)) resident row
-                       panels, per-tile gathers stay in VMEM.
+- ``"pallas"``       — gather-fused Pallas kernel; O(s·(m+n)) resident
+                       transposed row panels, per-tile gathers are VMEM
+                       row loads.
 - ``"materialized"`` — iteration-invariant loss matrix hoisted once
                        (O(s²) HBM, budget-gated); every call is a single
                        fused matvec + epilogue with zero gathers.
@@ -42,13 +43,14 @@ def resolve_impl(impl: str, s: int) -> str:
     return "pallas" if dispatch.backend() == "tpu" else "jnp"
 
 
-def _block_and_pad(rows, cols, block: Optional[int]):
+def _fused_setup(Cx, Cy, rows, cols, block: Optional[int]):
+    """Block size, padded size and the kernel's transposed row panels."""
     s = rows.shape[0]
     b = dispatch.block_size("spar_cost", block, cap=s)
     s_p = -(-s // b) * b
     rows_p = dispatch.pad_dim(rows.astype(jnp.int32), b)
     cols_p = dispatch.pad_dim(cols.astype(jnp.int32), b)
-    return b, s_p, rows_p, cols_p
+    return b, s_p, rows_p, cols_p, Cx[rows_p].T, Cy[cols_p].T
 
 
 def _vec(x, s_p: int):
@@ -63,10 +65,8 @@ def spar_cost_fused(Cx, Cy, rows, cols, t, off=0.0, loss: str = "l2",
                     interpret: Optional[bool] = None):
     """One-shot gather-fused cost: L @ t + off on the COO support, (s,)."""
     s = rows.shape[0]
-    b, s_p, rows_p, cols_p = _block_and_pad(rows, cols, block)
-    Xr = Cx[rows_p]
-    Yc = Cy[cols_p]
-    out = spar_cost_pallas(Xr, Yc, rows_p, cols_p,
+    b, s_p, rows_p, cols_p, XT, YT = _fused_setup(Cx, Cy, rows, cols, block)
+    out = spar_cost_pallas(XT, YT, rows_p, cols_p,
                            _vec(t, s_p), _vec(off, s_p), loss=loss,
                            bk=b, bl=b,
                            interpret=dispatch.interpret_mode(interpret))
@@ -105,13 +105,12 @@ def make_spar_cost_fn(Cx, Cy, rows, cols, loss: str, impl: str = "auto",
         return fn
 
     if impl == "pallas":
-        b, s_p, rows_p, cols_p = _block_and_pad(rows, cols, block)
-        Xr = Cx[rows_p]
-        Yc = Cy[cols_p]
+        b, s_p, rows_p, cols_p, XT, YT = _fused_setup(Cx, Cy, rows, cols,
+                                                      block)
         itp = dispatch.interpret_mode(interpret)
 
         def fn(t, off=0.0):
-            out = spar_cost_pallas(Xr, Yc, rows_p, cols_p,
+            out = spar_cost_pallas(XT, YT, rows_p, cols_p,
                                    _vec(t, s_p), _vec(off, s_p), loss=loss,
                                    bk=b, bl=b, interpret=itp)
             return out[:s]

@@ -35,7 +35,9 @@ def spar_cost_ref(Cx, Cy, rows, cols, tvals, loss: str, chunk: int = 1024):
         rk, ck = args                      # (chunk,)
         Gx = Cx[rk][:, rows]               # (chunk, s)
         Gy = Cy[ck][:, cols]               # (chunk, s)
-        return L(Gx, Gy) @ tvals           # (chunk,)
+        # HIGHEST: the oracle's matvec must not drop to bf16 passes on TPU
+        return jnp.dot(L(Gx, Gy), tvals,   # (chunk,)
+                       precision=lax.Precision.HIGHEST)
 
     out = lax.map(one, _chunked(rows, cols, chunk))
     return out.reshape(-1)[:s]

@@ -17,11 +17,14 @@ iteration — no C, no K, no separate logK intermediates.
 
 Two entry points (see DESIGN.md §3):
 
-- ``spar_cost_pallas`` — gather-fused. ``rows``/``cols`` ride in via
-  scalar prefetch; each (bk, bl) tile of Gx = Cx[rows][:, rows] (resp. Gy)
-  is gathered *inside* the kernel from the VMEM-resident row panels
-  Xr = Cx[rows], Yc = Cy[cols], so the (s, s) support blocks never touch
-  HBM. Memory high-water: O(s·(m+n)) for the panels.
+- ``spar_cost_pallas`` — gather-fused. Each k-block keeps the transposed
+  row panels XT = Cx[rows].T, YT = Cy[cols].T resident in VMEM as (m, bk)
+  and (n, bk) blocks; the l-block's rows/cols/t ride in as SMEM blocks, and
+  the kernel walks them one scalar at a time, reading the panel row
+  XT[rows[l], :] = Cx[rows[k-block], rows[l]] with a dynamic sublane
+  offset. So the (s, s) support blocks never touch HBM, and every gather
+  is a row load that Mosaic lowers (lane gathers ``x[:, idx]`` and vector
+  loads from SMEM do not). Memory high-water: O(s·(m+n)) for the panels.
 - ``spar_matvec_pallas`` — materialized-support fast mode. The loss matrix
   Lmat[k, l] = L(Gx, Gy) is **constant across all outer iterations**
   (rows/cols are fixed after sampling), so when the HBM budget allows it
@@ -31,6 +34,7 @@ Two entry points (see DESIGN.md §3):
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -51,23 +55,32 @@ def _loss_tile(loss: str, a, b):
     raise ValueError(loss)
 
 
-def _fused_kernel(rows_ref, cols_ref, xr_ref, yc_ref, t_ref, off_ref, o_ref,
-                  *, loss: str, bl: int, n_l: int):
+def _fused_kernel(rows_ref, cols_ref, t_ref, xt_ref, yt_ref, off_ref, o_ref,
+                  *, loss: str, bl: int, n_l: int, unroll: int):
     li = pl.program_id(1)
 
     @pl.when(li == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    ridx = rows_ref[pl.ds(li * bl, bl)]                  # (bl,) prefetched
-    cidx = cols_ref[pl.ds(li * bl, bl)]
-    gx = xr_ref[...].astype(jnp.float32)[:, ridx]        # (bk, bl) in VMEM
-    gy = yc_ref[...].astype(jnp.float32)[:, cidx]
-    t = t_ref[...].astype(jnp.float32)[0]                # (bl,)
-    e = _loss_tile(loss, gx, gy)
-    o_ref[...] += jax.lax.dot_general(
-        e, t, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)[None, :]
+    def body(j, accs):
+        # unrolled by hand (Mosaic's fori_loop takes no partial unroll),
+        # one (1, bk) partial sum per unrolled slot: independent add
+        # chains, each bl / unroll long
+        out = []
+        for u, acc in enumerate(accs):
+            l = j * unroll + u
+            gx = xt_ref[pl.ds(rows_ref[0, l], 1), :].astype(jnp.float32)
+            gy = yt_ref[pl.ds(cols_ref[0, l], 1), :].astype(jnp.float32)
+            out.append(acc + _loss_tile(loss, gx, gy) * t_ref[0, l])
+        return tuple(out)
+
+    zero = jnp.zeros(o_ref.shape, jnp.float32)
+    accs = jax.lax.fori_loop(0, bl // unroll, body, (zero,) * unroll)
+    while len(accs) > 1:                                 # pairwise sum
+        accs = tuple(accs[i] + accs[i + 1] if i + 1 < len(accs) else accs[i]
+                     for i in range(0, len(accs), 2))
+    o_ref[...] += accs[0]
 
     @pl.when(li == n_l - 1)
     def _epilogue():
@@ -76,37 +89,44 @@ def _fused_kernel(rows_ref, cols_ref, xr_ref, yc_ref, t_ref, off_ref, o_ref,
 
 @functools.partial(jax.jit,
                    static_argnames=("loss", "bk", "bl", "interpret"))
-def spar_cost_pallas(Xr, Yc, rows, cols, t, off, loss: str = "l2",
+def spar_cost_pallas(XT, YT, rows, cols, t, off, loss: str = "l2",
                      bk: int = 256, bl: int = 256, interpret: bool = True):
-    """Gather-fused COO cost: out = L(Xr[:, rows], Yc[:, cols]) @ t + off.
+    """Gather-fused COO cost: out[k] = Σ_l L(XT[rows_l, k], YT[cols_l, k]) t_l
+    + off[k].
 
-    Xr: (s_p, m) = Cx[rows], Yc: (s_p, n) = Cy[cols] row panels (gathered
-    once per support, outside); rows/cols: (s_p,) int32; t, off: (s_p,).
-    s_p must be a multiple of bk and bl (ops.py pads; padded tail has
-    t = 0 so it contributes nothing, and out rows ≥ s are sliced away).
+    XT: (m, s_p) = Cx[rows].T, YT: (n, s_p) = Cy[cols].T transposed row
+    panels (gathered once per support, outside); rows/cols: (s_p,) int32;
+    t, off: (s_p,). s_p must be a multiple of bk and bl (ops.py pads;
+    padded tail has t = 0 so it contributes nothing, and out rows ≥ s are
+    sliced away). Compiled for TPU, bk must be a multiple of 128 or s_p.
     Returns (s_p,) float32.
     """
-    s_p, m = Xr.shape
-    n = Yc.shape[1]
+    m, s_p = XT.shape
+    n = YT.shape[0]
     grid = (s_p // bk, s_p // bl)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+    def blocks(v):
+        # (s_p // bl, 1, bl): an SMEM block whose last two dims are the
+        # array's own, which Mosaic accepts for any bl (a 1-D SMEM block
+        # must match XLA's 1024-element tiling)
+        return v.reshape(grid[1], 1, bl)
+
+    smem = pl.BlockSpec((None, 1, bl), lambda k, l: (l, 0, 0),
+                        memory_space=pltpu.SMEM)
+    out = pl.pallas_call(
+        functools.partial(_fused_kernel, loss=loss, bl=bl, n_l=grid[1],
+                          unroll=math.gcd(bl, 8)),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bk, m), lambda k, l, r, c: (k, 0)),
-            pl.BlockSpec((bk, n), lambda k, l, r, c: (k, 0)),
-            pl.BlockSpec((1, bl), lambda k, l, r, c: (0, l)),
-            pl.BlockSpec((1, bk), lambda k, l, r, c: (0, k)),
+            smem, smem, smem,
+            pl.BlockSpec((m, bk), lambda k, l: (0, k)),
+            pl.BlockSpec((n, bk), lambda k, l: (0, k)),
+            pl.BlockSpec((1, bk), lambda k, l: (0, k)),
         ],
-        out_specs=pl.BlockSpec((1, bk), lambda k, l, r, c: (0, k)),
-    )
-    out = pl.pallas_call(
-        functools.partial(_fused_kernel, loss=loss, bl=bl, n_l=grid[1]),
-        grid_spec=grid_spec,
+        out_specs=pl.BlockSpec((1, bk), lambda k, l: (0, k)),
         out_shape=jax.ShapeDtypeStruct((1, s_p), jnp.float32),
         interpret=interpret,
-    )(rows.astype(jnp.int32), cols.astype(jnp.int32),
-      Xr, Yc, t.reshape(1, s_p), off.reshape(1, s_p))
+    )(blocks(rows.astype(jnp.int32)), blocks(cols.astype(jnp.int32)),
+      blocks(t.astype(jnp.float32)), XT, YT, off.reshape(1, s_p))
     return out[0]
 
 

@@ -255,7 +255,8 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, out_dir: Path,
         model_size=mesh.shape["model"], sp=sp)
     model = Model(cfg)
     rec = {"arch": arch, "shape": shape_name, "mesh": mesh_kind,
-           "mesh_shape": dict(mesh.shape), "kind": shape.kind, "tag": tag}
+           "mesh_shape": dict(mesh.shape), "kind": shape.kind, "tag": tag,
+           "device_kind": mesh.devices.flat[0].device_kind}
     t0 = time.time()
 
     fn, args, abstract = _build_fn(cfg, shape, mesh, use_flash, rules)
@@ -346,6 +347,7 @@ def run_gw_cell(mesh_kind: str, out_dir: Path, s_r: int = 8192,
     # matvec part and outer for cost assembly: conservative (report both).
     rec = {"arch": "spargw-engine", "shape": f"grid{s_r}x{s_c}",
            "mesh": mesh_kind, "mesh_shape": dict(mesh.shape),
+           "device_kind": mesh.devices.flat[0].device_kind,
            "kind": "gw", "tag": tag, "n_params": 0,
            "lower_s": 0.0, "compile_s": round(time.time() - t0, 2),
            "memory": {

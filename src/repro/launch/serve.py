@@ -51,8 +51,9 @@ def gw_main(args) -> None:
     """Drive a synthetic catalog workload through GWServer and print the
     per-request outcomes + the metrics summary."""
     import repro
-    from repro.serve import GWServer, ServeConfig
+    from repro.serve import GWServer, ServeConfig, enable_compilation_cache
 
+    print(f"compilation cache: {enable_compilation_cache()}")
     http_server = None
     if getattr(args, "metrics_port", 0):
         from repro.obs import serve_metrics_http

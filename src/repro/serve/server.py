@@ -35,11 +35,13 @@ ladder for that request only.
 """
 from __future__ import annotations
 
+import os
 import threading
 import time
 import warnings
 import weakref
 from dataclasses import dataclass, field
+from pathlib import Path
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
@@ -87,16 +89,9 @@ class ServeConfig:
     donate        — donate the stacked problem buffers to the executor
                     (they are per-flush temporaries; donation lets XLA
                     reuse them for outputs)
-    compilation_cache_dir — when set, enable JAX's *persistent*
-                    compilation cache at this path before the first
-                    dispatch: a fresh process serving the same bucket
-                    shapes deserializes yesterday's executables instead
-                    of recompiling them (the dominant cold-start cost).
-                    The knob is process-global (it flips ``jax.config``
-                    for every jit in the process, not just the server's)
-                    and sticky — enabling is one-way for the process
-                    lifetime, later servers may point elsewhere only
-                    with a fresh process.
+
+    The persistent compilation cache is process-wide, not a server knob:
+    entry points call :func:`enable_compilation_cache` once at start-up.
     """
     buckets: Tuple[int, ...] = DEFAULT_BUCKETS
     max_batch: int = 8
@@ -105,7 +100,6 @@ class ServeConfig:
     cache_entries: int = 128
     on_failure: str = "fallback"
     donate: bool = True
-    compilation_cache_dir: Optional[str] = None
 
     def __post_init__(self):
         if self.on_failure not in ("none", "fallback"):
@@ -116,18 +110,28 @@ class ServeConfig:
             raise ValueError("max_batch must be >= 1")
 
 
-def enable_compilation_cache(cache_dir: str) -> None:
-    """Point JAX's persistent compilation cache at ``cache_dir``.
+# the checkout's own cache directory (listed in .gitignore); a fixed path,
+# because the cache key includes it — a directory that moves never hits
+DEFAULT_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
-    Caches every XLA executable compiled from now on (and reloads on
-    cache hits in future processes). The thresholds are zeroed so even
+
+def enable_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX's own reading of it
+    stands and no directory is set here; otherwise the cache lives at
+    :data:`DEFAULT_CACHE_DIR`. A fresh process serving the same bucket
+    shapes then deserializes executables instead of recompiling them (the
+    dominant cold-start cost). The thresholds are zeroed so even
     sub-second solver compiles are persisted — a GW serving process
-    compiles a handful of large executables, not thousands of tiny
-    ones, so write amplification is a non-issue.
+    compiles a handful of large executables, not thousands of tiny ones.
+    Process-wide: it flips ``jax.config`` for every jit in the process.
     """
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", str(DEFAULT_CACHE_DIR))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return jax.config.jax_compilation_cache_dir
 
 
 @dataclass
@@ -172,6 +176,7 @@ class _Request:
     batch: Any = None
     lane: int = -1
     result: Optional[RequestResult] = None
+    error: Optional[BaseException] = None   # the batch failed to dispatch
 
 
 @dataclass
@@ -192,16 +197,15 @@ def _flusher_main(server_ref, interval_s: float,
 
     Holds only a weakref to the server so an abandoned (un-``close``d)
     server can still be garbage collected; the loop exits when the
-    server dies or ``stop`` is set.
+    server dies or ``stop`` is set. A bucket that fails to dispatch hands
+    its exception to its requests (``_flush_bucket``), which ``result``
+    raises — so nothing needs catching here.
     """
     while not stop.wait(interval_s):
         server = server_ref()
         if server is None:
             return
-        try:
-            server._pump(source="timer")
-        except Exception:  # noqa: BLE001 — the flusher must outlive hiccups
-            pass
+        server._pump(source="timer")
         del server
 
 
@@ -210,8 +214,6 @@ class GWServer:
 
     def __init__(self, config: Optional[ServeConfig] = None):
         self.config = config or ServeConfig()
-        if self.config.compilation_cache_dir:
-            enable_compilation_cache(self.config.compilation_cache_dir)
         self.cache = GeometryCache(self.config.cache_entries)
         self.metrics = ServeMetrics()
         self._requests: Dict[int, _Request] = {}
@@ -320,20 +322,29 @@ class GWServer:
                 p0, s0, k0 = items[0]
                 items.extend([(p0, disarm_fault(s0), k0)]
                              * (n_lanes - len(items)))
-            with span("serve.batch", lanes=n_lanes, real=len(rids)):
-                stacked_p, stacked_s, stacked_k = stack_items(items)
-            with span("serve.dispatch", lanes=n_lanes,
-                      source=source) as sp:
-                before = self._exec_cache_size()
-                with warnings.catch_warnings():
-                    # CPU backends can't alias every donated buffer —
-                    # harmless
-                    warnings.filterwarnings(
-                        "ignore",
-                        message="Some donated buffers were not usable")
-                    out = self._exec(stacked_p, stacked_s, stacked_k)
-                sp["compiled"] = bool(before >= 0
-                                      and self._exec_cache_size() > before)
+            try:
+                with span("serve.batch", lanes=n_lanes, real=len(rids)):
+                    stacked_p, stacked_s, stacked_k = stack_items(items)
+                with span("serve.dispatch", lanes=n_lanes,
+                          source=source) as sp:
+                    before = self._exec_cache_size()
+                    with warnings.catch_warnings():
+                        # CPU backends can't alias every donated buffer —
+                        # harmless
+                        warnings.filterwarnings(
+                            "ignore",
+                            message="Some donated buffers were not usable")
+                        out = self._exec(stacked_p, stacked_s, stacked_k)
+                    sp["compiled"] = bool(
+                        before >= 0 and self._exec_cache_size() > before)
+            except Exception as e:  # noqa: BLE001 — handed to result()
+                # a compile or dispatch error belongs to these requests:
+                # whoever flushed (a submit, the timer thread), each
+                # request's result() raises it
+                for rid in rids:
+                    req = self._requests[rid]
+                    req.state, req.error, req.item = "done", e, None
+                return
             batch = _Batch(out=out, rids=rids, n_lanes=n_lanes)
             self.metrics.record_batch(len(rids), n_lanes)
             for lane, rid in enumerate(rids):
@@ -370,6 +381,8 @@ class GWServer:
                 return req.result
             if req.state == "queued":
                 self._flush_bucket(req.sig)
+            if req.error is not None:
+                raise req.error
             batch = req.batch
         # block outside the lock: the flusher and other submitters keep
         # running while XLA computes

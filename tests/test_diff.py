@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 from repro.api.geometry import Geometry
 from repro.api.problem import QuadraticProblem
@@ -100,7 +99,7 @@ class TestDenseGradient:
         return Cx, value, value_unrolled, n
 
     def test_matches_fd_and_unrolled(self):
-        with enable_x64():
+        with jax.enable_x64():
             Cx, value, value_unrolled, n = self._setup()
             D = _sym_dir(np.random.default_rng(0), n)
             an = float(jnp.sum(jax.grad(value)(Cx) * D))
@@ -111,7 +110,7 @@ class TestDenseGradient:
 
     def test_unrolled_forward_matches_solver(self):
         # faithfulness contract: same budget, same trajectory
-        with enable_x64():
+        with jax.enable_x64():
             Cx, value, value_unrolled, _ = self._setup()
             np.testing.assert_allclose(float(value(Cx)),
                                        float(value_unrolled(Cx)), rtol=1e-10)
@@ -160,7 +159,7 @@ class TestSparGradient:
         return Cx, value, value_unrolled, n
 
     def test_matches_unrolled(self):
-        with enable_x64():
+        with jax.enable_x64():
             Cx, value, value_unrolled, n = self._setup(100, 300)
             D = _sym_dir(np.random.default_rng(1), n)
             an = float(jnp.sum(jax.grad(value)(Cx) * D))
@@ -177,7 +176,7 @@ class TestSparGradient:
         assert _rel(an, fd) <= 2e-3, (an, fd)
 
     def test_unrolled_forward_matches_solver(self):
-        with enable_x64():
+        with jax.enable_x64():
             Cx, value, value_unrolled, _ = self._setup(100, 300)
             np.testing.assert_allclose(float(value(Cx)),
                                        float(value_unrolled(Cx)), rtol=1e-10)
@@ -217,7 +216,7 @@ class TestLowRankGradient:
         return x, value, value_unrolled
 
     def test_matches_fd_and_unrolled(self):
-        with enable_x64():
+        with jax.enable_x64():
             x, value, value_unrolled = self._setup()
             D = jnp.asarray(np.random.default_rng(2).standard_normal(x.shape))
             an = float(jnp.sum(jax.grad(value)(x) * D))
@@ -333,7 +332,7 @@ class TestComposition:
 
 class TestFusedAndMarginals:
     def test_fgw_feature_and_alpha_grads_match_fd(self):
-        with enable_x64():
+        with jax.enable_x64():
             n = 10
             x, y = _clouds(n, n, 0.1, 0)
             kf = jax.random.PRNGKey(9)
@@ -362,7 +361,7 @@ class TestFusedAndMarginals:
         """Unbalanced marginals/lam are *live* envelope paths (the KL
         penalties read (a, b) in the value recompute): exact, FD to
         ~1e-10 at any budget."""
-        with enable_x64():
+        with jax.enable_x64():
             n = 10
             x, y = _clouds(n, n, 0.4, 11)
             Cx, Cy = _sqdist(x), _sqdist(y)

@@ -47,7 +47,7 @@ def test_compressed_psum_under_shard_map(multi_device_runner):
     multi_device_runner("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from repro.distrib.compression import dp_allreduce_grads
 mesh = jax.make_mesh((4,), ("data",))
 x = jax.random.normal(jax.random.PRNGKey(0), (4, 64))
@@ -55,7 +55,7 @@ def f(x_local):
     g = {"w": x_local[0]}
     out = dp_allreduce_grads(g, "data", compress=True)
     return out["w"]
-y = shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P(), check_rep=False)(x)
+y = shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P(), check_vma=False)(x)
 ref = np.mean(np.array(x), axis=0)
 err = np.max(np.abs(np.array(y) - ref))
 bound = np.abs(np.array(x)).max()/127.0*1.5 + 1e-6

@@ -89,3 +89,16 @@ def test_ssd_intra_kernel_sweep(shape):
     ref = jax.vmap(ssd_intra_ref)(xdt, cs, Bm, Cm)
     np.testing.assert_allclose(np.array(got), np.array(ref), rtol=1e-4,
                                atol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# roofline peaks: keyed by device kind, no default
+# ---------------------------------------------------------------------------
+
+def test_roofline_peaks_by_device_kind():
+    from benchmarks.roofline import peaks
+    v5e = peaks("TPU v5 lite")
+    assert (v5e.flops, v5e.hbm_bw) == (197e12, 819e9)
+    for kind in ("cpu", "TPU v4", "unrecorded"):
+        with pytest.raises(ValueError, match="no published peaks"):
+            peaks(kind)

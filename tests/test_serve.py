@@ -444,24 +444,38 @@ def test_keyless_dense_fallback_returns_batched_output():
 # persistent compilation cache
 # ---------------------------------------------------------------------------
 
-def test_compilation_cache_skips_recompile_in_fresh_process(tmp_path):
-    """Two identical server processes sharing a cache dir: the first
-    populates it, the second (fresh process, cold in-memory caches)
-    deserializes every executable — no new cache entries."""
+def _run_child(code: str, *args: str, env_extra=None, drop=()):
     import os
     import pathlib
     import subprocess
     import sys
+
+    root = pathlib.Path(__file__).resolve().parent.parent
+    env = {k: v for k, v in os.environ.items() if k not in drop}
+    env.update(PYTHONPATH=str(root / "src"), PYTHONHASHSEED="0",
+               **(env_extra or {}))
+    proc = subprocess.run([sys.executable, "-c", code, *args],
+                          capture_output=True, text=True, env=env, cwd=root,
+                          timeout=420)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    return proc.stdout
+
+
+def test_compilation_cache_skips_recompile_in_fresh_process(tmp_path):
+    """Two identical server processes sharing JAX_COMPILATION_CACHE_DIR:
+    the first populates it, the second (fresh process, cold in-memory
+    caches) deserializes every executable — no new cache entries, and
+    the helper leaves JAX's reading of the variable as it is."""
     import textwrap
 
     code = textwrap.dedent("""
         import sys
         import jax, jax.numpy as jnp
         import repro
-        from repro.serve import GWServer, ServeConfig
+        from repro.serve import GWServer, ServeConfig, enable_compilation_cache
 
-        server = GWServer(ServeConfig(compilation_cache_dir=sys.argv[1],
-                                      max_batch=1))
+        assert enable_compilation_cache() == sys.argv[1]
+        server = GWServer(ServeConfig(max_batch=1))
         n = 20
         x = jax.random.normal(jax.random.PRNGKey(0), (n, 2))
         y = jax.random.normal(jax.random.PRNGKey(1), (n, 2))
@@ -473,17 +487,11 @@ def test_compilation_cache_skips_recompile_in_fresh_process(tmp_path):
         assert not res.failed, res.status_name
         print("VALUE", float(res.value))
     """)
-    root = pathlib.Path(__file__).resolve().parent.parent
-    env = {**os.environ, "PYTHONPATH": str(root / "src"),
-           "PYTHONHASHSEED": "0"}
 
     def run_once():
-        proc = subprocess.run(
-            [sys.executable, "-c", code, str(tmp_path)],
-            capture_output=True, text=True, env=env, cwd=root, timeout=420)
-        assert proc.returncode == 0, proc.stderr[-2000:]
-        value = [ln for ln in proc.stdout.splitlines()
-                 if ln.startswith("VALUE")][0]
+        out = _run_child(code, str(tmp_path), env_extra={
+            "JAX_COMPILATION_CACHE_DIR": str(tmp_path)})
+        value = [ln for ln in out.splitlines() if ln.startswith("VALUE")][0]
         entries = sorted(p.name for p in tmp_path.rglob("*") if p.is_file())
         return value, entries
 
@@ -493,3 +501,65 @@ def test_compilation_cache_skips_recompile_in_fresh_process(tmp_path):
     assert value2 == value1
     assert entries2 == entries1, (
         f"second process recompiled: {set(entries2) - set(entries1)}")
+
+
+def test_compilation_cache_defaults_to_fixed_checkout_dir():
+    """Without JAX_COMPILATION_CACHE_DIR the cache lives at one fixed
+    directory inside the checkout (no temp name, pid or time in it)."""
+    import pathlib
+
+    from repro.serve.server import DEFAULT_CACHE_DIR
+
+    code = ("from repro.serve import enable_compilation_cache\n"
+            "print(enable_compilation_cache())\n")
+    out = [_run_child(code, drop=("JAX_COMPILATION_CACHE_DIR",))
+           for _ in range(2)]
+    root = pathlib.Path(__file__).resolve().parent.parent
+    assert DEFAULT_CACHE_DIR == root / ".jax_cache"
+    assert out[0] == out[1] == f"{DEFAULT_CACHE_DIR}\n"
+
+
+# ---------------------------------------------------------------------------
+# dispatch errors reach the caller
+# ---------------------------------------------------------------------------
+
+def _failing_exec(*args):
+    raise RuntimeError("compile failed: injected")
+
+
+def test_timer_flush_dispatch_error_reaches_result():
+    """A bucket flushed by the background timer that fails to compile:
+    its requests carry the exception and result() raises it."""
+    import time
+
+    from repro.obs.span import spans
+
+    probs = [_problem(s, 14) for s in range(2)]
+    srv = GWServer(ServeConfig(max_batch=8, max_wait_s=0.2,
+                               on_failure="none"))
+    srv._exec = _failing_exec
+    rids = [srv.submit(p, CLEAN) for p in probs]
+    reqs = [srv._requests[rid] for rid in rids]
+    deadline = time.time() + 30
+    while (any(r.state != "done" for r in reqs)   # no server call: only
+           and time.time() < deadline):           # the timer can flush
+        time.sleep(0.01)
+    srv.close()
+    assert any(r["name"] == "serve.dispatch" and r.get("source") == "timer"
+               for r in spans())
+    for rid in rids:
+        assert srv.poll(rid) == "done"
+        with pytest.raises(RuntimeError, match="injected"):
+            srv.result(rid)
+
+
+def test_explicit_flush_dispatch_error_reaches_result():
+    srv = GWServer(ServeConfig(max_batch=8, max_wait_s=60.0,
+                               flush_thread=False, on_failure="none"))
+    srv._exec = _failing_exec
+    rid = srv.submit(_problem(0, 14), CLEAN)
+    srv.flush()                              # the flusher itself survives
+    with pytest.raises(RuntimeError, match="injected"):
+        srv.result(rid)
+    with pytest.raises(RuntimeError, match="injected"):
+        srv.results([rid])

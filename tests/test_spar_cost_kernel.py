@@ -185,6 +185,19 @@ def test_autotune_caches_best_block(monkeypatch):
     assert recs and recs[-1]["best_block"] == 8
 
 
+def test_autotune_raises_when_every_candidate_fails():
+    dispatch.register("_test_tune_fail", default_block=128)
+
+    def bench(block):
+        raise ValueError(f"block {block} over budget")
+
+    with pytest.raises(RuntimeError, match="every candidate") as info:
+        dispatch.autotune("_test_tune_fail", [8, 32], bench, reps=1)
+    assert isinstance(info.value.__cause__, ValueError)
+    assert not [r for r in dispatch.autotune_records()
+                if r["family"] == "_test_tune_fail"]
+
+
 def test_pad_unpad_roundtrip():
     x = jnp.arange(12, dtype=jnp.float32).reshape(3, 4)
     xp, shape = dispatch.pad_to_multiple(x, (8, 128))
