@@ -1,0 +1,7 @@
+"""``device.idle_share``, read in the all-pairs cells, where it moves
+``pairs_per_s``."""
+import harness
+
+_BASE = harness.load_layer_metric("device.idle_share")
+UNIT, LAYER, read = _BASE.UNIT, _BASE.LAYER, _BASE.read
+MOVES = "pairs_per_s"
