@@ -89,13 +89,9 @@ def tune_matvec_block(n: int, ratio: int):
     rows, cols, t = _support(jax.random.PRNGKey(1), a, b, Cx, Cy, s)
     Lmat = materialize_loss(Cx, Cy, rows, cols, "l2")
     reps = 2 if dispatch.backend() == "tpu" else 1   # interpret mode is slow
-    # one matvec reads the (s, s) loss matrix once and does 2s² flops —
-    # the analytic counts that place the winner on the roofline
     best = dispatch.autotune(
         "spar_cost", (64, 128, 256),
-        lambda blk: spar_matvec(Lmat, t, block=blk), reps=reps,
-        flops_per_call=2.0 * s * s,
-        bytes_per_call=4.0 * s * s)
+        lambda blk: spar_matvec(Lmat, t, block=blk), reps=reps)
     record(f"spar_cost/autotune/n{n}/s{ratio}n", 0.0, f"block={best}")
     path = dispatch.dump_autotune_records()
     if path is not None:

@@ -1,0 +1,7 @@
+"""``serve.queue_ms_per_solve``, read in the all-pairs cells, where it moves
+``pairs_per_s``."""
+import harness
+
+_BASE = harness.load_layer_metric("serve.queue_ms_per_solve")
+UNIT, LAYER, read = _BASE.UNIT, _BASE.LAYER, _BASE.read
+MOVES = "pairs_per_s"

@@ -31,6 +31,11 @@ from repro.core.utils import safe_div
 _NEG_INF = -1e30   # proxy for -inf that stays NaN-free under arithmetic
 
 
+# every Sinkhorn entry point traces under this named scope, so its ops
+# carry "gw.sinkhorn" in the HLO op_name metadata
+_scoped = jax.named_scope("gw.sinkhorn")
+
+
 def _finite(x):
     return jnp.where(jnp.isfinite(x) & (x > _NEG_INF / 2), x, 0.0)
 
@@ -68,6 +73,7 @@ def _scaling_loop(body, init, iters: int, tol: float):
 # Dense
 # ---------------------------------------------------------------------------
 
+@_scoped
 def sinkhorn(a, b, K, iters: int, differentiable: bool = False,
              tol: float = 0.0):
     """Plain Sinkhorn scaling (Alg. 1 step 5): u = a ⊘ (K v), v = b ⊘ (Kᵀ u)."""
@@ -94,6 +100,7 @@ def sinkhorn(a, b, K, iters: int, differentiable: bool = False,
     return u[:, None] * K * v[None, :]
 
 
+@_scoped
 def sinkhorn_log(a, b, logK, iters: int, differentiable: bool = False,
                  tol: float = 0.0):
     """Log-domain Sinkhorn. Returns the coupling T (dense)."""
@@ -122,6 +129,7 @@ def sinkhorn_log(a, b, logK, iters: int, differentiable: bool = False,
     return jnp.exp(logK + f[:, None] + g[None, :])
 
 
+@_scoped
 def sinkhorn_unbalanced(a, b, K, lam, eps, iters: int, tol: float = 0.0):
     """Plain unbalanced Sinkhorn (Alg. 3 step 9): exponent λ̄/(λ̄+ε̄)."""
     m, n = K.shape
@@ -139,6 +147,7 @@ def sinkhorn_unbalanced(a, b, K, lam, eps, iters: int, tol: float = 0.0):
     return u[:, None] * K * v[None, :]
 
 
+@_scoped
 def sinkhorn_unbalanced_log(a, b, logK, lam, eps, iters: int,
                             tol: float = 0.0):
     """Log-domain unbalanced Sinkhorn: log u = ρ (log a - lse(logK + log v))."""
@@ -179,6 +188,7 @@ def segment_logsumexp(vals, segs, num: int):
 
 
 @partial(jax.jit, static_argnames=("m", "n", "iters", "tol"))
+@_scoped
 def sparse_sinkhorn(a, b, rows, cols, vals, m: int, n: int, iters: int,
                     tol: float = 0.0):
     """Plain-domain sparse Sinkhorn on a COO kernel (paper-faithful).
@@ -201,6 +211,7 @@ def sparse_sinkhorn(a, b, rows, cols, vals, m: int, n: int, iters: int,
 
 
 @partial(jax.jit, static_argnames=("m", "n", "iters", "tol"))
+@_scoped
 def sparse_sinkhorn_logdomain(a, b, rows, cols, logvals, m: int, n: int,
                               iters: int, tol: float = 0.0):
     """Log-domain sparse Sinkhorn (production default; small-ε safe)."""
@@ -220,6 +231,7 @@ def sparse_sinkhorn_logdomain(a, b, rows, cols, logvals, m: int, n: int,
 
 
 @partial(jax.jit, static_argnames=("m", "n", "iters", "tol"))
+@_scoped
 def sparse_sinkhorn_unbalanced(a, b, rows, cols, vals, lam, eps,
                                m: int, n: int, iters: int, tol: float = 0.0):
     """Plain-domain unbalanced sparse Sinkhorn (Alg. 3 step 9)."""
@@ -238,6 +250,7 @@ def sparse_sinkhorn_unbalanced(a, b, rows, cols, vals, lam, eps,
 
 
 @partial(jax.jit, static_argnames=("m", "n", "iters", "tol"))
+@_scoped
 def sparse_sinkhorn_unbalanced_log(a, b, rows, cols, logvals, lam, eps,
                                    m: int, n: int, iters: int,
                                    tol: float = 0.0):

@@ -143,11 +143,12 @@ def health_loop(step_fn: Callable, err_fn: Callable, T0, max_iters: int,
         done = conv | dead
         T_in = fault.apply(T, i) if fault is not None and \
             fault.site == "cost" else T
-        if scaled_step:
-            scale = jnp.float32(rescue_factor) ** n_rescues
-            T_new = step_fn(T_in, scale)
-        else:
-            T_new = step_fn(T_in)
+        with jax.named_scope("gw.pga_step"):
+            if scaled_step:
+                scale = jnp.float32(rescue_factor) ** n_rescues
+                T_new = step_fn(T_in, scale)
+            else:
+                T_new = step_fn(T_in)
         if fault is not None and fault.site == "iterate":
             T_new = fault.apply(T_new, i)
         l1 = _tree_l1(T_new)

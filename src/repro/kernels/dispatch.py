@@ -186,9 +186,7 @@ _AUTOTUNE_RECORDS: list[dict] = []
 
 
 def autotune(family: str, candidates: Iterable[int],
-             bench_fn: Callable[[int], object], reps: int = 3,
-             flops_per_call: Optional[float] = None,
-             bytes_per_call: Optional[float] = None) -> int:
+             bench_fn: Callable[[int], object], reps: int = 3) -> int:
     """Time ``bench_fn(block)`` over candidate block sizes; cache the best.
 
     The winner feeds subsequent :func:`block_size` resolutions for
@@ -196,11 +194,8 @@ def autotune(family: str, candidates: Iterable[int],
     in-process record list that ``benchmarks/roofline.py`` reports.
     Candidates that raise are skipped (e.g. blocks over the VMEM budget);
     when every candidate raises, so does this, with the last error chained.
-
-    ``flops_per_call`` / ``bytes_per_call`` (caller-supplied analytic
-    counts for one ``bench_fn`` invocation) turn the winner's timing into
-    achieved GFLOP/s and GB/s — recorded on the autotune record and
-    exported as ``repro_autotune_*`` gauges for roofline placement.
+    The timings are host wall clock: they rank blocks, and are no
+    measure of a kernel's device time or utilization.
     """
     timings: dict[int, float] = {}
     last_error: Optional[Exception] = None
@@ -232,17 +227,6 @@ def autotune(family: str, candidates: Iterable[int],
     reg.gauge("repro_autotune_best_time_seconds",
               "best per-call time of the autotune winner",
               family=family, backend=backend()).set(best_s)
-    if flops_per_call is not None and best_s > 0:
-        record["gflops"] = flops_per_call / best_s / 1e9
-        reg.gauge("repro_autotune_gflops",
-                  "achieved GFLOP/s of the autotune winner (roofline y)",
-                  family=family, backend=backend()).set(record["gflops"])
-    if bytes_per_call is not None and best_s > 0:
-        record["gbytes_per_s"] = bytes_per_call / best_s / 1e9
-        reg.gauge("repro_autotune_gbytes_per_s",
-                  "achieved GB/s of the autotune winner",
-                  family=family, backend=backend()).set(
-                      record["gbytes_per_s"])
     _AUTOTUNE_RECORDS.append(record)
     return best
 

@@ -21,6 +21,7 @@ from __future__ import annotations
 
 from typing import Callable, Optional
 
+import jax
 import jax.numpy as jnp
 
 from repro.kernels import dispatch
@@ -95,10 +96,24 @@ def make_spar_cost_fn(Cx, Cy, rows, cols, loss: str, impl: str = "auto",
     materialization) happens here, once; inside a jit'd solver XLA hoists
     it out of the outer ``lax.scan``, so every iteration pays only the
     fused matvec (materialized) or tiled gather+loss+matvec (pallas).
+    The setup and every call are traced under the ``gw.cost`` named
+    scope, whichever implementation runs, so their ops carry it in the
+    HLO ``op_name`` metadata.
     """
-    s = rows.shape[0]
-    impl = resolve_impl(impl, s)
+    with jax.named_scope("gw.cost"):
+        fn = _cost_fn(Cx, Cy, rows, cols, loss, resolve_impl(
+            impl, rows.shape[0]), chunk, block, interpret)
 
+    def scoped(t, off=0.0):
+        with jax.named_scope("gw.cost"):
+            return fn(t, off)
+    return scoped
+
+
+def _cost_fn(Cx, Cy, rows, cols, loss: str, impl: str, chunk: int,
+             block: Optional[int], interpret: Optional[bool]):
+    """The unscoped closure of one resolved ``impl``."""
+    s = rows.shape[0]
     if impl == "jnp":
         def fn(t, off=0.0):
             return spar_cost_ref(Cx, Cy, rows, cols, t, loss, chunk) + off

@@ -30,6 +30,10 @@ Two entry points (see DESIGN.md §3):
   (rows/cols are fixed after sampling), so when the HBM budget allows it
   is hoisted once and every iteration collapses to this fused
   matvec + epilogue with zero gathers.
+
+Each ``pallas_call`` is named after its entry point (``name=``): that is
+the name its custom call carries in the compiled HLO and in profiler
+captures, whatever the Python functions around it are called.
 """
 from __future__ import annotations
 
@@ -125,6 +129,7 @@ def spar_cost_pallas(XT, YT, rows, cols, t, off, loss: str = "l2",
         out_specs=pl.BlockSpec((1, bk), lambda k, l: (0, k)),
         out_shape=jax.ShapeDtypeStruct((1, s_p), jnp.float32),
         interpret=interpret,
+        name="spar_cost_pallas",
     )(blocks(rows.astype(jnp.int32)), blocks(cols.astype(jnp.int32)),
       blocks(t.astype(jnp.float32)), XT, YT, off.reshape(1, s_p))
     return out[0]
@@ -169,5 +174,6 @@ def spar_matvec_pallas(Lmat, t, off, bk: int = 256, bl: int = 256,
         out_specs=pl.BlockSpec((1, bk), lambda k, l: (0, k)),
         out_shape=jax.ShapeDtypeStruct((1, s_p), jnp.float32),
         interpret=interpret,
+        name="spar_matvec_pallas",
     )(Lmat, t.reshape(1, s_p), off.reshape(1, s_p))
     return out[0]

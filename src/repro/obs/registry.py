@@ -4,7 +4,7 @@ One :class:`MetricsRegistry` per process (the module-level default,
 reachable via :func:`registry`) that every subsystem registers into:
 ``ServeMetrics`` (request/batch/latency), ``GeometryCache`` (hit / miss /
 eviction), ``kernels/dispatch`` (per-family resolution counts, autotune
-results, achieved GFLOP/s) and the solve front door (per-status solve
+winners) and the solve front door (per-status solve
 counts, rescue and fallback totals). Two exporters read it:
 
 * :meth:`MetricsRegistry.snapshot` — a JSON-safe nested dict (the

@@ -23,11 +23,26 @@ span also enters a ``jax.profiler.TraceAnnotation`` of the same name, so
 host-side spans land as named regions in XLA profiler traces with zero
 changes at the call sites.
 
-Overhead per span is two ``perf_counter`` calls plus one deque append
-(~1 µs) — safe on the serving hot path.
+Every record carries an ``id`` (process-unique) and the ``parent_id``
+of the span open beneath it on the same thread, so one request or batch
+can be followed through nested stages. An interval that starts in one
+call and ends in another (a request's wait in its bucket) cannot be a
+``with`` block; :func:`record` appends it, finished, to the same ring.
+
+One clock: records are stamped in integer nanoseconds (``start_ns`` /
+``end_ns``) by :func:`now_ns`, the wall clock that ``jax.profiler``
+stamps its host events with (tsl's ``GetCurrentTimeNanos``, i.e.
+``CLOCK_REALTIME``). A record therefore lands on the profiler's
+timeline once the capture's ``profile_start_time`` is added to the
+capture's relative times. ``start_s`` (relative to this module's import)
+and ``duration_s`` are derived from the same two readings.
+
+Overhead per span is two clock reads plus one deque append (~1 µs) —
+safe on the serving hot path.
 """
 from __future__ import annotations
 
+import itertools
 import os
 import threading
 import time
@@ -40,7 +55,9 @@ _TRUTHY = {"1", "true", "yes", "on"}
 # bounded: a long-lived server must not grow span history without limit
 MAX_SPANS = 65536
 
-_T0 = time.perf_counter()        # process-relative clock zero
+now_ns = time.time_ns           # the profiler's host clock (see module doc)
+_T0_NS = now_ns()                # zero of the process-relative start_s
+_ids = itertools.count(1)        # next() is atomic under the GIL
 _lock = threading.Lock()
 _records: "deque[dict]" = deque(maxlen=MAX_SPANS)
 _tls = threading.local()
@@ -68,25 +85,45 @@ def _stack() -> List[dict]:
     return stack
 
 
+def _new_record(name: str, parent: Optional[dict], depth: int,
+                attrs: dict) -> dict:
+    rec: Dict = {
+        "name": name,
+        "id": next(_ids),
+        "parent_id": parent["id"] if parent else None,
+        "start_ns": 0,
+        "end_ns": 0,
+        "start_s": 0.0,
+        "duration_s": 0.0,
+        "depth": depth,
+        "parent": parent["name"] if parent else None,
+        "thread": threading.current_thread().name,
+    }
+    rec.update(attrs)
+    return rec
+
+
+def _stamp(rec: dict, start_ns: int, end_ns: Optional[int] = None) -> None:
+    rec["start_ns"] = start_ns
+    rec["start_s"] = (start_ns - _T0_NS) * 1e-9
+    if end_ns is not None:
+        rec["end_ns"] = end_ns
+        rec["duration_s"] = (end_ns - start_ns) * 1e-9
+
+
 @contextmanager
 def span(name: str, **attrs) -> Iterator[dict]:
     """Record a named wall-clock span; yields its (mutable) record dict.
 
     Extra keyword arguments become attributes of the record; more can be
     attached to the yielded dict before the block exits. Records carry
-    ``name`` / ``start_s`` (process-relative) / ``duration_s`` /
-    ``depth`` / ``parent`` / ``thread``.
+    ``name`` / ``id`` / ``parent_id`` / ``start_ns`` / ``end_ns`` /
+    ``start_s`` (process-relative) / ``duration_s`` / ``depth`` /
+    ``parent`` (the parent's name) / ``thread``. ``start_ns`` is set
+    before the block runs; the end is stamped when it exits.
     """
     stack = _stack()
-    rec: Dict = {
-        "name": name,
-        "start_s": time.perf_counter() - _T0,
-        "duration_s": 0.0,
-        "depth": len(stack),
-        "parent": stack[-1]["name"] if stack else None,
-        "thread": threading.current_thread().name,
-    }
-    rec.update(attrs)
+    rec = _new_record(name, stack[-1] if stack else None, len(stack), attrs)
     stack.append(rec)
     ann = None
     if _use_xla():
@@ -96,11 +133,12 @@ def span(name: str, **attrs) -> Iterator[dict]:
             ann.__enter__()
         except Exception:  # noqa: BLE001 — profiling must never break a solve
             ann = None
-    t_in = time.perf_counter()
+    # stamped inside the annotation, so the record sits within its event
+    _stamp(rec, now_ns())
     try:
         yield rec
     finally:
-        rec["duration_s"] = time.perf_counter() - t_in
+        _stamp(rec, rec["start_ns"], now_ns())
         if ann is not None:
             try:
                 ann.__exit__(None, None, None)
@@ -111,15 +149,31 @@ def span(name: str, **attrs) -> Iterator[dict]:
             _records.append(rec)
 
 
+def record(name: str, start_ns: int, end_ns: int, **attrs) -> dict:
+    """Append a finished span for ``[start_ns, end_ns]`` (:func:`now_ns`
+    readings) and return its record.
+
+    For an interval that starts and ends in different calls, such as a
+    request's wait in its bucket. It has no parent (``parent_id`` None,
+    depth 0) and enters no ``TraceAnnotation``: the host is not doing
+    that work, and a profiler region would mislabel what it is doing.
+    """
+    rec = _new_record(name, None, 0, attrs)
+    _stamp(rec, start_ns, end_ns)
+    with _lock:
+        _records.append(rec)
+    return rec
+
+
 def spans() -> List[dict]:
     """Snapshot of completed span records, ordered by start time.
 
     (Completion order interleaves children before parents; sorting by
-    ``start_s`` restores the lifecycle order a reader expects.)
+    ``start_ns`` restores the lifecycle order a reader expects.)
     """
     with _lock:
         out = [dict(r) for r in _records]
-    return sorted(out, key=lambda r: r["start_s"])
+    return sorted(out, key=lambda r: r["start_ns"])
 
 
 def clear_spans() -> None:

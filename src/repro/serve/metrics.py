@@ -76,11 +76,18 @@ class ServeMetrics:
                     "pow2-padding filler lanes dispatched").inc(
                         n_lanes - n_real)
 
-    def record_result(self, submitted_at: float, dispatched_at: float,
+    def record_result(self, submitted_at: float, queue_wait: float,
                       failed: bool, fell_back: bool) -> float:
-        now = time.perf_counter()
-        latency = now - submitted_at
-        queue_wait = dispatched_at - submitted_at
+        """Count one answered request; returns its latency in seconds.
+
+        submitted_at — ``record_submit``'s reading; the latency runs from
+                       it to now
+        queue_wait   — seconds from the request's append to its bucket to
+                       the start of the flush that took it (the
+                       duration of its ``serve.queue`` span), so stacking
+                       and dispatch are not counted as waiting
+        """
+        latency = time.perf_counter() - submitted_at
         self.n_completed += 1
         self.latencies_s.add(latency)
         self.queue_waits_s.add(queue_wait)
@@ -92,7 +99,8 @@ class ServeMetrics:
         reg.histogram("repro_serve_latency_seconds",
                       "submit-to-result request latency").observe(latency)
         reg.histogram("repro_serve_queue_wait_seconds",
-                      "submit-to-dispatch queue wait").observe(queue_wait)
+                      "wait in the bucket: enqueue to the start of its "
+                      "flush").observe(queue_wait)
         if failed:
             reg.counter("repro_serve_failed_total",
                         "requests unhealthy after the batched attempt").inc()

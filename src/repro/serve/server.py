@@ -32,15 +32,26 @@ bucket-mates' bits), and a lane that comes back DIVERGED/STALLED is —
 under ``on_failure="fallback"`` — re-solved solo through
 ``repro.solve(..., on_failure="fallback")``, walking the PR-6 solver
 ladder for that request only.
+
+Spans (``repro.obs.span``) follow one request by its ``rid`` and one
+flush by its ``batch`` (a per-server counter): ``serve.submit`` and its
+``serve.pad`` (``rid``); ``serve.queue`` (``rid``, ``batch``,
+``source``), a record-only span from the request's append to its bucket
+to the start of the flush that took it; ``serve.batch`` (stacking) and
+``serve.dispatch`` (``batch``); ``serve.block`` (``rid``, ``batch``), the
+wait on the device; ``serve.collect`` (``rid``, ``batch``), the lane
+slice, health checks and result building, with any ``serve.fallback``
+inside it. Filler lanes get no spans of their own.
 """
 from __future__ import annotations
 
+import itertools
 import os
 import threading
 import time
 import warnings
 import weakref
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from pathlib import Path
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
@@ -61,7 +72,7 @@ from repro.serve.batching import (
     stack_items,
 )
 from repro.obs.registry import registry
-from repro.obs.span import span
+from repro.obs.span import now_ns, record, span
 from repro.serve.cache import GeometryCache
 from repro.serve.metrics import ServeMetrics
 
@@ -172,6 +183,8 @@ class _Request:
     shape: Tuple[int, int]
     padded_shape: Tuple[int, int]
     submitted_at: float
+    enqueued_ns: int = 0              # appended to its bucket (span clock)
+    queue_wait_s: float = 0.0         # its serve.queue span's duration
     state: str = "queued"             # queued -> running -> done
     batch: Any = None
     lane: int = -1
@@ -184,7 +197,7 @@ class _Batch:
     out: Any                          # stacked GWOutput (device futures)
     rids: List[int]                   # real lanes, in lane order
     n_lanes: int
-    dispatched_at: float = field(default_factory=time.perf_counter)
+    id: int                           # the per-server batch counter
 
 
 def _run_lane(problem, solver, key):
@@ -218,7 +231,8 @@ class GWServer:
         self.metrics = ServeMetrics()
         self._requests: Dict[int, _Request] = {}
         self._queues: Dict[Any, List[int]] = {}
-        self._next_rid = 0
+        self._rids = itertools.count()      # next() is atomic under the GIL
+        self._batch_ids = itertools.count()
         self._lock = threading.RLock()
         donate = (0,) if self.config.donate else ()
         self._exec = jax.jit(jax.vmap(_run_lane), donate_argnums=donate)
@@ -253,7 +267,8 @@ class GWServer:
                key: Optional[jax.Array] = None,
                validate: bool = True) -> int:
         """Enqueue one solve request; returns its request id."""
-        with span("serve.submit"):
+        rid = next(self._rids)
+        with span("serve.submit", rid=rid):
             if solver is None:
                 solver = select_solver(problem)
             elif isinstance(solver, str):
@@ -268,7 +283,7 @@ class GWServer:
             m, n = problem.shape
             mb = bucket_for(m, self.config.buckets)
             nb = bucket_for(n, self.config.buckets)
-            with span("serve.pad"):
+            with span("serve.pad", rid=rid):
                 padded = pad_problem(
                     problem, mb, nb,
                     geom_x=self.cache.padded(problem.geom_x, mb),
@@ -276,13 +291,12 @@ class GWServer:
             item = (padded, solver, key)
             sig = batch_signature(item)
             with self._lock:
-                rid = self._next_rid
-                self._next_rid += 1
                 req = _Request(rid=rid, problem=problem, solver=solver,
                                key=key, item=item, sig=sig, shape=(m, n),
                                padded_shape=(mb, nb),
                                submitted_at=self.metrics.record_submit())
                 self._requests[rid] = req
+                req.enqueued_ns = now_ns()
                 self._queues.setdefault(sig, []).append(rid)
                 if len(self._queues[sig]) >= self.config.max_batch:
                     self._flush_bucket(sig, source="full")
@@ -312,10 +326,14 @@ class GWServer:
                     self._flush_bucket(sig, source="flush")
 
     def _flush_bucket(self, sig, source: str = "call") -> None:
+        """Stack one bucket and dispatch it. Each real request's wait,
+        from its append to the bucket to the start of this flush's
+        ``serve.batch`` span, is recorded as a ``serve.queue`` span."""
         with self._lock:
             rids = self._queues.pop(sig, [])
             if not rids:
                 return
+            bid = next(self._batch_ids)
             items = [self._requests[rid].item for rid in rids]
             n_lanes = next_pow2(len(items))
             if len(items) < n_lanes:
@@ -323,9 +341,11 @@ class GWServer:
                 items.extend([(p0, disarm_fault(s0), k0)]
                              * (n_lanes - len(items)))
             try:
-                with span("serve.batch", lanes=n_lanes, real=len(rids)):
+                with span("serve.batch", batch=bid, lanes=n_lanes,
+                          real=len(rids)) as sp:
+                    started_ns = sp["start_ns"]
                     stacked_p, stacked_s, stacked_k = stack_items(items)
-                with span("serve.dispatch", lanes=n_lanes,
+                with span("serve.dispatch", batch=bid, lanes=n_lanes,
                           source=source) as sp:
                     before = self._exec_cache_size()
                     with warnings.catch_warnings():
@@ -341,14 +361,20 @@ class GWServer:
                 # a compile or dispatch error belongs to these requests:
                 # whoever flushed (a submit, the timer thread), each
                 # request's result() raises it
+                failed_ns = now_ns()
                 for rid in rids:
                     req = self._requests[rid]
+                    record("serve.queue", req.enqueued_ns, failed_ns,
+                           rid=rid, batch=bid, source=source, error=True)
                     req.state, req.error, req.item = "done", e, None
                 return
-            batch = _Batch(out=out, rids=rids, n_lanes=n_lanes)
+            batch = _Batch(out=out, rids=rids, n_lanes=n_lanes, id=bid)
             self.metrics.record_batch(len(rids), n_lanes)
             for lane, rid in enumerate(rids):
                 req = self._requests[rid]
+                req.queue_wait_s = record(
+                    "serve.queue", req.enqueued_ns, started_ns, rid=rid,
+                    batch=bid, source=source)["duration_s"]
                 req.state = "running"
                 req.batch = batch
                 req.lane = lane
@@ -386,32 +412,38 @@ class GWServer:
             batch = req.batch
         # block outside the lock: the flusher and other submitters keep
         # running while XLA computes
-        with span("serve.block"):
+        with span("serve.block", rid=rid, batch=batch.id):
             jax.block_until_ready(batch.out.value)
         with self._lock:
             if req.result is not None:     # lost a race to another thread
                 return req.result
-            lane = req.lane
-            out = jax.tree.map(lambda x: x[lane], batch.out)
-            failed = bool(np.asarray(out.status.code) >= STALLED) or not \
-                bool(np.all(np.isfinite(np.asarray(out.value))))
-            fell_back = False
-            if failed and self.config.on_failure == "fallback":
-                with span("serve.fallback", rid=rid):
-                    out, fell_back = self._fallback(req)
-            status_name = (STATUS_NAMES[int(np.asarray(out.status.code))]
-                           if out.status is not None else "UNKNOWN")
-            latency = self.metrics.record_result(
-                req.submitted_at, batch.dispatched_at, failed, fell_back)
+            with span("serve.collect", rid=rid, batch=batch.id):
+                req.result = self._collect(req, batch)
             req.state = "done"
-            req.result = RequestResult(
-                rid=rid, value=float(np.asarray(out.value)), output=out,
-                status=out.status, status_name=status_name, failed=failed,
-                fell_back=fell_back, shape=req.shape,
-                padded_shape=req.padded_shape, latency_s=latency)
             req.batch = None          # release the stacked batch for GC
             req.item = None
             return req.result
+
+    def _collect(self, req: _Request, batch: _Batch) -> RequestResult:
+        """One request's lane of a finished batch, checked, and re-solved
+        solo if it came back unhealthy (under ``on_failure="fallback"``)."""
+        lane = req.lane
+        out = jax.tree.map(lambda x: x[lane], batch.out)
+        failed = bool(np.asarray(out.status.code) >= STALLED) or not \
+            bool(np.all(np.isfinite(np.asarray(out.value))))
+        fell_back = False
+        if failed and self.config.on_failure == "fallback":
+            with span("serve.fallback", rid=req.rid, batch=batch.id):
+                out, fell_back = self._fallback(req)
+        status_name = (STATUS_NAMES[int(np.asarray(out.status.code))]
+                       if out.status is not None else "UNKNOWN")
+        latency = self.metrics.record_result(
+            req.submitted_at, req.queue_wait_s, failed, fell_back)
+        return RequestResult(
+            rid=req.rid, value=float(np.asarray(out.value)), output=out,
+            status=out.status, status_name=status_name, failed=failed,
+            fell_back=fell_back, shape=req.shape,
+            padded_shape=req.padded_shape, latency_s=latency)
 
     def results(self, rids: Sequence[int]) -> List[RequestResult]:
         """Drain a set of requests (flushes any still queued)."""

@@ -229,6 +229,95 @@ def test_span_stack_is_thread_local():
     assert all(r["depth"] == 0 and r["parent"] is None for r in recs)
 
 
+def test_record_lands_in_ring_with_id_and_no_annotation(monkeypatch):
+    from repro.obs.span import now_ns, record
+
+    entered = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            entered.append(self.name)
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    obs.configure(xla_annotations=True)
+    try:
+        obs.clear_spans()
+        t0 = now_ns()
+        with obs.span("open"):       # a record made inside a span
+            rec = record("waited", t0, t0 + 2_500_000, rid=7)
+    finally:
+        obs.configure(None)
+    by_name = {r["name"]: r for r in obs.spans()}
+    assert entered == ["open"]       # the span's annotation, not the record's
+    got = by_name["waited"]
+    assert got == rec
+    assert isinstance(got["id"], int) and got["id"] != by_name["open"]["id"]
+    assert got["parent_id"] is None and got["parent"] is None
+    assert got["depth"] == 0 and got["rid"] == 7
+    assert (got["start_ns"], got["end_ns"]) == (t0, t0 + 2_500_000)
+    assert got["duration_s"] == pytest.approx(2.5e-3)
+
+
+def test_parent_id_follows_the_enclosing_span_across_threads():
+    obs.clear_spans()
+    ready = threading.Barrier(2)
+
+    def work(tag):
+        with obs.span("root", tag=tag):
+            ready.wait()             # both roots open at once
+            with obs.span("mid", tag=tag):
+                with obs.span("leaf", tag=tag):
+                    pass
+
+    threads = [threading.Thread(target=work, args=(i,)) for i in range(2)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    recs = obs.spans()
+    assert len({r["id"] for r in recs}) == len(recs) == 6
+    by_id = {r["id"]: r for r in recs}
+    for r in recs:
+        if r["name"] == "root":
+            assert r["parent_id"] is None
+            continue
+        parent = by_id[r["parent_id"]]
+        assert parent["name"] == r["parent"]
+        assert parent["tag"] == r["tag"]          # never the other thread's
+        assert parent["thread"] == r["thread"]
+        assert parent["start_ns"] <= r["start_ns"] <= r["end_ns"] \
+            <= parent["end_ns"]
+
+
+def test_span_ns_stamps_agree_with_seconds():
+    import time
+
+    from repro.obs.span import now_ns
+    obs.clear_spans()
+    before = now_ns()
+    with obs.span("outer"):
+        time.sleep(0.01)
+        with obs.span("inner"):
+            time.sleep(0.002)
+    after = now_ns()
+    recs = obs.spans()
+    outer = recs[0]
+    assert before <= outer["start_ns"] <= outer["end_ns"] <= after
+    for r in recs:
+        assert r["duration_s"] == pytest.approx(
+            (r["end_ns"] - r["start_ns"]) * 1e-9, abs=1e-12)
+    # start_s is the same reading on a process-relative zero
+    assert recs[1]["start_s"] - outer["start_s"] == pytest.approx(
+        (recs[1]["start_ns"] - outer["start_ns"]) * 1e-9, abs=1e-9)
+    assert outer["duration_s"] >= 0.012
+
+
 def test_solve_emits_lifecycle_spans():
     obs.clear_spans()
     problem = _problem(seed=3)
@@ -295,7 +384,7 @@ def test_serve_metrics_latency_store_is_bounded():
     m = ServeMetrics(sample_cap=8)
     for _ in range(50):
         t = m.record_submit()
-        m.record_result(t, t, failed=False, fell_back=False)
+        m.record_result(t, 0.0, failed=False, fell_back=False)
     assert len(m.latencies_s) == 8 and m.latencies_s.n_seen == 50
     assert m.summary()["n_completed"] == 50
     # the PR-7 shim: serve.metrics.percentiles is the obs definition

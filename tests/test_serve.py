@@ -563,3 +563,109 @@ def test_explicit_flush_dispatch_error_reaches_result():
         srv.result(rid)
     with pytest.raises(RuntimeError, match="injected"):
         srv.results([rid])
+
+
+# ---------------------------------------------------------------------------
+# request- and batch-scoped spans
+# ---------------------------------------------------------------------------
+
+def test_spans_follow_each_request_through_its_batch():
+    """Two full batches and one timer flush with a filler lane: every real
+    request has exactly one ``serve.queue`` and one ``serve.collect``,
+    tied by ``rid`` and ``batch`` to the ``serve.batch`` that carried it;
+    filler lanes have neither, and a repeated ``result`` adds none."""
+    import time
+
+    from repro.obs.span import clear_spans, spans
+
+    srv = GWServer(ServeConfig(max_batch=2, max_wait_s=0.1,
+                               on_failure="none"))
+    cfg = srv.config
+    try:
+        srv.config = dataclasses.replace(cfg, max_wait_s=3600.0)  # no timer
+        clear_spans()
+        rids = [srv.submit(_problem(s, 14), CLEAN) for s in range(4)]
+        srv.config = cfg
+        rids.append(srv.submit(_problem(4, 14), CLEAN))
+        last = srv._requests[rids[-1]]
+        deadline = time.time() + 30
+        while last.state == "queued" and time.time() < deadline:
+            time.sleep(0.01)                 # only the timer can flush it
+        for rid in rids:
+            srv.result(rid)
+        recs = spans()
+        n_before = len(recs)
+        srv.result(rids[0])                  # cached: records nothing
+        assert len(spans()) == n_before
+    finally:
+        srv.close()
+
+    def named(name):
+        return [r for r in recs if r["name"] == name]
+
+    batches = {r["batch"]: r for r in named("serve.batch")}
+    assert len(batches) == 3
+    assert sorted((b["real"], b["lanes"]) for b in batches.values()) == [
+        (1, 2), (2, 2), (2, 2)]
+    for name in ("serve.queue", "serve.block", "serve.collect"):
+        assert sorted(r["rid"] for r in named(name)) == rids, name
+    queue = {r["rid"]: r for r in named("serve.queue")}
+    block = {r["rid"]: r for r in named("serve.block")}
+    collect = {r["rid"]: r for r in named("serve.collect")}
+    for rid in rids:
+        bid = queue[rid]["batch"]
+        assert block[rid]["batch"] == collect[rid]["batch"] == bid
+        assert queue[rid]["start_ns"] <= queue[rid]["end_ns"] \
+            <= batches[bid]["start_ns"]
+        assert collect[rid]["start_ns"] >= block[rid]["end_ns"]
+        assert "error" not in queue[rid]
+    assert [queue[rid]["source"] for rid in rids] == ["full"] * 4 + ["timer"]
+    # each batch carried as many requests as it had real lanes
+    for bid, b in batches.items():
+        assert sum(q["batch"] == bid for q in queue.values()) == b["real"]
+    assert queue[0]["batch"] == queue[1]["batch"] != queue[2]["batch"]
+    # a flush run by a full submit stays its child: admission subtracts it
+    submits = {r["rid"]: r for r in named("serve.submit")}
+    for r in named("serve.batch") + named("serve.dispatch"):
+        if r["batch"] != queue[4]["batch"]:
+            owner = submits[rids[2 * r["batch"] + 1]]
+            assert r["parent"] == "serve.submit"
+            assert r["parent_id"] == owner["id"]
+        else:
+            assert r["parent"] is None
+    for r in named("serve.pad"):
+        assert r["parent_id"] == submits[r["rid"]]["id"]
+
+
+def test_failed_flush_ends_each_queue_span_with_an_error():
+    from repro.obs.span import clear_spans, spans
+
+    srv = GWServer(ServeConfig(max_batch=8, max_wait_s=60.0,
+                               flush_thread=False, on_failure="none"))
+    srv._exec = _failing_exec
+    clear_spans()
+    rids = [srv.submit(_problem(s, 14), CLEAN) for s in range(2)]
+    srv.flush()
+    queue = [r for r in spans() if r["name"] == "serve.queue"]
+    batch = [r for r in spans() if r["name"] == "serve.batch"]
+    assert sorted(r["rid"] for r in queue) == rids
+    assert all(r["error"] is True and r["batch"] == batch[0]["batch"]
+               and r["end_ns"] >= batch[0]["start_ns"] for r in queue)
+    assert srv._requests[rids[0]].queue_wait_s == 0.0   # never dispatched
+    assert not [r for r in spans() if r["name"] == "serve.collect"]
+
+
+def test_queue_wait_metric_is_the_queue_span():
+    """``ServeMetrics`` times the wait from enqueue to the flush's start,
+    the ``serve.queue`` span, not to the end of dispatch."""
+    from repro.obs.span import clear_spans, spans
+
+    srv = GWServer(ServeConfig(max_batch=2, max_wait_s=60.0,
+                               flush_thread=False, on_failure="none"))
+    clear_spans()
+    rids = [srv.submit(_problem(s, 14), CLEAN) for s in range(2)]
+    srv.results(rids)
+    waits = sorted(r["duration_s"] for r in spans()
+                   if r["name"] == "serve.queue")
+    assert len(waits) == 2
+    assert sorted(srv.metrics.queue_waits_s) == waits

@@ -8,7 +8,10 @@ The topology is described inside a module fixture, never at import time:
 only one process may load the TPU library, so describing it while the
 module is imported would break collection under several test workers.
 """
+import json
 import os
+import re
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -100,3 +103,48 @@ def test_served_spar_gw_batch_compiles(one_chip, monkeypatch):
     server = GWServer(ServeConfig(flush_thread=False))
     compiled = server._exec.lower(*stacked).compile()
     _assert_kernel(compiled)
+
+
+COST_KERNELS = json.loads((Path(__file__).resolve().parents[1] / "benchmarks"
+                           / "gwbench" / "cost_kernels.json").read_text())
+SCOPES = ("gw.cost", "gw.sinkhorn", "gw.pga_step")
+# the benchmark's spar cells: n = 1000 on the fused kernel, n = 500 on the
+# materialized matvec (what "auto" picks on the chip at these sizes)
+SERVED = {"spar_cost_pallas": (1000, 1024, "pallas"),
+          "spar_matvec_pallas": (500, 512, "materialized")}
+
+
+def test_served_kernels_cover_the_attributed_names():
+    assert sorted(SERVED) == sorted(
+        n for names in COST_KERNELS.values() for n in names)
+
+
+@pytest.mark.parametrize("kernel", sorted(SERVED))
+def test_served_spar_batch_keeps_kernel_names_and_scopes(one_chip, kernel,
+                                                         monkeypatch):
+    """The names the benchmark attributes device time by survive into
+    the compiled executable of a served 2-lane spar_gw batch: an
+    instruction named after the Pallas kernel, and the three named scopes
+    in the HLO ``op_name`` metadata."""
+    import repro
+    from repro.serve import GWServer, ServeConfig
+    from repro.serve.batching import pad_problem, stack_items
+
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    n, bucket, impl = SERVED[kernel]
+    pts = np.random.default_rng(0).standard_normal((n, 2)).astype(np.float32)
+    C = np.sqrt(((pts[:, None] - pts[None]) ** 2).sum(-1))
+    geom = repro.Geometry(jnp.asarray(C), jnp.full(n, 1.0 / n, jnp.float32))
+    problem = pad_problem(repro.QuadraticProblem(geom, geom), bucket, bucket)
+    solver = repro.SparGWSolver(s=16 * n, cost_impl=impl)
+    item = (problem, solver, jax.random.PRNGKey(0))
+    stacked = jax.tree.map(lambda x: _spec(one_chip, x.shape, x.dtype),
+                           stack_items([item, item]))
+    server = GWServer(ServeConfig(flush_thread=False))
+    text = server._exec.lower(*stacked).compile().as_text()
+    names = re.findall(r"^\s*(?:ROOT )?%?([\w.-]+) = .*custom_call_target="
+                       r"\"tpu_custom_call\"", text, re.M)
+    assert names and all(kernel in name for name in names), names
+    op_names = re.findall(r'op_name="([^"]*)"', text)
+    for scope in SCOPES:
+        assert any(scope in o for o in op_names), scope
