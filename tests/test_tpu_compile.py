@@ -8,6 +8,7 @@ The topology is described inside a module fixture, never at import time:
 only one process may load the TPU library, so describing it while the
 module is imported would break collection under several test workers.
 """
+import importlib
 import json
 import os
 import re
@@ -20,6 +21,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 V5E = "v5e:2x2"
+V5E_HBM_BYTES = int(15.75 * 2**30)      # what the compiler lets a program use
 
 
 @pytest.fixture(scope="module")
@@ -83,26 +85,31 @@ def test_fused_spar_cost_compiles(one_chip, m, s_p):
     assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
-def test_served_spar_gw_batch_compiles(one_chip, monkeypatch):
-    """GWServer's vmapped executable for a 2-lane spar_gw bucket at
-    n = 1024, with the cost assembly on the gather-fused kernel."""
+def _served_spar_batch(sharding, n, bucket, impl, lanes=2):
+    """GWServer's compiled executable for a spar_gw bucket of ``lanes``
+    Gaussian point clouds of n points, s = 16n, on cost_impl ``impl``."""
     import repro
     from repro.serve import GWServer, ServeConfig
-    from repro.serve.batching import pad_problem, stack_items
+    from repro.serve.batching import pad_problem
 
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
-    n = 1000
     pts = np.random.default_rng(0).standard_normal((n, 2)).astype(np.float32)
     C = np.sqrt(((pts[:, None] - pts[None]) ** 2).sum(-1))
     geom = repro.Geometry(jnp.asarray(C), jnp.full(n, 1.0 / n, jnp.float32))
-    problem = pad_problem(repro.QuadraticProblem(geom, geom), 1024, 1024)
-    solver = repro.SparGWSolver(s=16 * n, cost_impl="pallas")
+    problem = pad_problem(repro.QuadraticProblem(geom, geom), bucket, bucket)
+    solver = repro.SparGWSolver(s=16 * n, cost_impl=impl)
     item = (problem, solver, jax.random.PRNGKey(0))
-    stacked = jax.tree.map(lambda x: _spec(one_chip, x.shape, x.dtype),
-                           stack_items([item, item]))
+    # the shapes ``stack_items`` gives, without stacking the lanes
+    stacked = jax.tree.map(lambda x: _spec(sharding, (lanes,) + np.shape(x),
+                                           jnp.asarray(x).dtype), item)
     server = GWServer(ServeConfig(flush_thread=False))
-    compiled = server._exec.lower(*stacked).compile()
-    _assert_kernel(compiled)
+    return server._exec.lower(*stacked).compile()
+
+
+def test_served_spar_gw_batch_compiles(one_chip, monkeypatch):
+    """GWServer's vmapped executable for a 2-lane spar_gw bucket at
+    n = 1024, with the cost assembly on the gather-fused kernel."""
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    _assert_kernel(_served_spar_batch(one_chip, 1000, 1024, "pallas"))
 
 
 COST_KERNELS = json.loads((Path(__file__).resolve().parents[1] / "benchmarks"
@@ -126,25 +133,109 @@ def test_served_spar_batch_keeps_kernel_names_and_scopes(one_chip, kernel,
     the compiled executable of a served 2-lane spar_gw batch: an
     instruction named after the Pallas kernel, and the three named scopes
     in the HLO ``op_name`` metadata."""
-    import repro
-    from repro.serve import GWServer, ServeConfig
-    from repro.serve.batching import pad_problem, stack_items
-
     monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
-    n, bucket, impl = SERVED[kernel]
-    pts = np.random.default_rng(0).standard_normal((n, 2)).astype(np.float32)
-    C = np.sqrt(((pts[:, None] - pts[None]) ** 2).sum(-1))
-    geom = repro.Geometry(jnp.asarray(C), jnp.full(n, 1.0 / n, jnp.float32))
-    problem = pad_problem(repro.QuadraticProblem(geom, geom), bucket, bucket)
-    solver = repro.SparGWSolver(s=16 * n, cost_impl=impl)
-    item = (problem, solver, jax.random.PRNGKey(0))
-    stacked = jax.tree.map(lambda x: _spec(one_chip, x.shape, x.dtype),
-                           stack_items([item, item]))
-    server = GWServer(ServeConfig(flush_thread=False))
-    text = server._exec.lower(*stacked).compile().as_text()
+    text = _served_spar_batch(one_chip, *SERVED[kernel]).as_text()
     names = re.findall(r"^\s*(?:ROOT )?%?([\w.-]+) = .*custom_call_target="
                        r"\"tpu_custom_call\"", text, re.M)
     assert names and all(kernel in name for name in names), names
     op_names = re.findall(r'op_name="([^"]*)"', text)
     for scope in SCOPES:
         assert any(scope in o for o in op_names), scope
+
+
+def _computations(text):
+    """Compiled HLO text -> {computation name: its instruction lines}."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.-]+) .*\{$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif line == "}":
+            cur = None
+        elif cur is not None:
+            cur.append(line)
+    return comps
+
+
+def _reach(comps, root):
+    """Every computation ``root`` runs: loop bodies and conditions,
+    fusions, calls, reductions' appliers and conditional branches."""
+    seen, todo = set(), [root]
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            todo += re.findall(r"(?:condition|body|calls|to_apply)=%([\w.-]+)",
+                               line)
+            for group in re.findall(r"branch_computations=\{([^}]*)\}", line):
+                todo += [b.strip().lstrip("%") for b in group.split(",")]
+    return seen
+
+
+@pytest.mark.parametrize("kernel", sorted(SERVED))
+def test_served_spar_inner_sinkhorn_loop_has_no_scatter(one_chip, kernel,
+                                                        monkeypatch):
+    """In a served 2-lane spar_gw batch the inner Sinkhorn loop (the one
+    nested in the outer PGA loop) runs on the dense cell grid: no scatter
+    and no gather in its body, which keeps the ``gw.sinkhorn`` scope; the
+    merge of the support into the grid sits outside it, in the outer
+    step (a scatter onto the 2 · bucket² cells)."""
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    n, bucket, impl = SERVED[kernel]
+    comps = _computations(
+        _served_spar_batch(one_chip, n, bucket, impl).as_text())
+    loops = {}      # while instruction -> (computation holding it, body)
+    for name, lines in comps.items():
+        for line in lines:
+            w = re.search(r"%([\w.-]+) = .* while\(.*body=%([\w.-]+)", line)
+            if w:
+                loops[w.group(1)] = (name, w.group(2))
+    reach = {w: _reach(comps, body) for w, (_, body) in loops.items()}
+    nests = [(o, i) for o in loops for i in loops
+             if i != o and loops[i][0] in reach[o]]
+    assert len(nests) == 1, loops
+    outer, inner = nests[0]
+
+    def lines_of(loop):
+        return [line for c in reach[loop] for line in comps[c]]
+
+    assert not any(f" {op}(" in line for line in lines_of(inner)
+                   for op in ("scatter", "gather"))
+    assert any("gw.sinkhorn" in line for line in lines_of(inner))
+    # the merge: a scatter onto both lanes' (bucket, bucket) grids
+    grids = [re.search(r"= f32\[([\d,]+)\]\S* scatter\(", line)
+             for line in lines_of(outer)]
+    assert 2 * bucket * bucket in {
+        int(np.prod([int(d) for d in g.group(1).split(",")]))
+        for g in grids if g}
+
+
+def _peak_bytes(compiled):
+    ma = compiled.memory_analysis()
+    return (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+
+
+def test_served_spar_dense_grid_is_free_at_its_bound(one_chip, monkeypatch):
+    """At the largest square bucket that keeps the inner Sinkhorn's dense
+    cell grid (4096², 2**24 cells a lane) and GWServer's default 8 lanes,
+    the grid adds nothing to the compiled peak of a served spar_gw batch:
+    it lives in what the cost step frees, and the batch fits the chip.
+    (Cost on the jnp path: the gather-fused kernel's (m, s) panels alone
+    want 24 GiB at 8 lanes, on either layout.)"""
+    from repro.obs import registry
+    sk = importlib.import_module("repro.core.sinkhorn")
+    peak = {}
+    for layout, cells_max in (("coo", 0), ("dense", sk._DENSE_CELLS_MAX)):
+        monkeypatch.setattr(sk, "_DENSE_CELLS_MAX", cells_max)
+        jax.clear_caches()      # the layout is fixed when a shape traces
+        traces = registry().counter("repro_sinkhorn_layout_total",
+                                    layout=layout)
+        before = traces.value
+        peak[layout] = _peak_bytes(_served_spar_batch(
+            one_chip, 4096, 4096, "jnp", lanes=8))
+        assert traces.value > before, layout
+    assert peak["dense"] <= 1.01 * peak["coo"], peak
+    assert peak["dense"] < V5E_HBM_BYTES, peak
