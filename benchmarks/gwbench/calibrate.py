@@ -14,6 +14,7 @@ the control's), for every number the solver family knows, whether a
 limit reads it or not. ``run.py`` never runs this.
 """
 import argparse
+import gc
 import json
 import sys
 from pathlib import Path
@@ -42,19 +43,27 @@ def main(argv=None) -> int:
     from repro.serve import GWServer, enable_compilation_cache
 
     enable_compilation_cache()
-    server = GWServer(harness.serve_config(cell.traffic))
     names = sorted(cell.family.NUMBERS)
     seeds = [int(s) for s in args.seeds.split(",")]
     lines = []
     for n, seed in enumerate(seeds):
         traffic = harness.build_traffic(cell.config, cell.traffic, seed)
         client = harness.Client(cell, traffic)
+        # a server per seed, closed before the reference runs, as in a
+        # run: a server keeps every request it answered, and with it the
+        # request's geometries on the device
+        server = GWServer(harness.serve_config(cell.traffic))
         harness.warm_up(server, client, cell)
         _, done = cell.loop.run(server, client, cell.traffic, args.seconds)
         checked = harness.served_answers(cell, harness.sample_for_check(
             done, cell.traffic["check_sample"], seed))
-        rec = {"seed": seed, "completed": len(done), "checked": len(checked),
-               "failed": sum(1 for d in done if harness.failed(d)),
+        n_done, n_failed = len(done), sum(1 for d in done
+                                          if harness.failed(d))
+        server.close()
+        del server, client, done
+        gc.collect()
+        rec = {"seed": seed, "completed": n_done, "checked": len(checked),
+               "failed": n_failed,
                "program": harness.compared_numbers(cell, traffic, checked,
                                                    names)}
         if n < args.control:
@@ -63,13 +72,12 @@ def main(argv=None) -> int:
                 answers=harness.control_answers(cell, traffic, checked))
         print(json.dumps(rec), flush=True)
         lines.append(rec)
-        del client, done
-    server.close()
     summary = {"workload": args.workload, "seeds": len(lines),
                "lower": {k: max(r["program"][k] for r in lines)
                          for k in names},
-               "upper": {k: min(r["control"][k] for r in lines
-                                if "control" in r) for k in names}}
+               "upper": {k: min((r["control"][k] for r in lines
+                                 if "control" in r), default=None)
+                         for k in names}}
     print(json.dumps(summary), flush=True)
     if args.out:
         Path(args.out).parent.mkdir(parents=True, exist_ok=True)

@@ -114,10 +114,17 @@ def _loss_rows(Cx, Cy, rows, cols, rk, ck):
     return d * d
 
 
+# the largest support loss matrix contracted in one product: at HIGHEST
+# precision the product holds split copies of it, about twice its size
+# again (s = 32000: 12.4 GB in all against a v5e's 15.75), so past this
+# the contraction runs block by block over its rows
+WHOLE_LOSS_BYTES = 2**31
+
+
 @partial(jax.jit, static_argnames=("m", "n", "outer", "inner", "block",
-                                   "dtype"))
+                                   "blocked", "dtype"))
 def _spar(Cx, a, Cy, b, rows, cols, epsilon, *, m: int, n: int, outer: int,
-          inner: int, block: int, dtype):
+          inner: int, block: int, blocked: bool, dtype):
     s = rows.shape[0]
     Cx, Cy, a, b = (x.astype(dtype) for x in (Cx, Cy, a, b))
     # the support's loss matrix, built in row blocks so the gathers stay
@@ -127,11 +134,20 @@ def _spar(Cx, a, Cy, b, rows, cols, epsilon, *, m: int, n: int, outer: int,
     rk = jnp.pad(rows, (0, pad)).reshape(nb, block)
     ck = jnp.pad(cols, (0, pad)).reshape(nb, block)
     L = jax.lax.map(lambda rc: _loss_rows(Cx, Cy, rows, cols, *rc),
-                    (rk, ck)).reshape(nb * block, s)[:s]
+                    (rk, ck))
 
-    def contract(t):
-        return jnp.dot(L, t, precision=_hp(dtype),
-                       preferred_element_type=dtype)
+    if blocked:
+        def contract(t):
+            return jax.lax.map(
+                lambda Lb: jnp.dot(Lb, t, precision=_hp(dtype),
+                                   preferred_element_type=dtype),
+                L).reshape(nb * block)[:s]
+    else:
+        L = L.reshape(nb * block, s)[:s]
+
+        def contract(t):
+            return jnp.dot(L, t, precision=_hp(dtype),
+                           preferred_element_type=dtype)
 
     pa = jnp.sqrt(a) / jnp.sum(jnp.sqrt(a))
     pb = jnp.sqrt(b) / jnp.sum(jnp.sqrt(b))
@@ -176,8 +192,10 @@ def spar_gw(Cx, a, Cy, b, rows, cols, solver: dict, dtype=jnp.float32,
             block: int = 1024):
     """(value, coupling values on the support) of Algorithm 2 with the
     configuration's parameters, on the given support."""
+    s = len(rows)
     return _spar(jnp.asarray(Cx), jnp.asarray(a), jnp.asarray(Cy),
                  jnp.asarray(b), jnp.asarray(rows, jnp.int32),
                  jnp.asarray(cols, jnp.int32), solver["epsilon"],
                  m=len(a), n=len(b), outer=solver["outer_iters"],
-                 inner=solver["inner_iters"], block=block, dtype=dtype)
+                 inner=solver["inner_iters"], block=block,
+                 blocked=4 * s * s > WHOLE_LOSS_BYTES, dtype=dtype)

@@ -4,7 +4,9 @@ Everything that belongs to one configuration, traffic mix, cell, metric
 or kind of solver lives in a file of its own under this directory and is
 found by the name that ``BENCHMARK.json`` or a data file gives it:
 
-    configs/<config>.json         a deployment: geometry, pinned solver
+    configs/<config>.json         a deployment: geometry, pinned solver,
+                                  optional ``problem`` terms (lam,
+                                  fused_penalty)
     traffic/<traffic>.json        a mix: loop, requests in flight, pool,
                                   order, ServeConfig fields, check sample
     limits/<workload>.json        the limit of each number compared
@@ -12,7 +14,10 @@ found by the name that ``BENCHMARK.json`` or a data file gives it:
     pools/<kind>.py               a traffic's ``pool.kind``: the problems
     loops/<kind>.py               a traffic's ``loop``: how load is offered
     families/<family>.py          a config's ``solver.family``: served
-                                  answer, plain reference, numbers compared
+                                  answer, plain reference, numbers compared;
+                                  ``<family>.<variant>.py`` where the
+                                  ``problem`` block makes the problem fused
+                                  or unbalanced
     end_to_end/<metric>.py        a reader: ``read(ctx) -> float``
     layer_metrics/<metric>.py     a reader: ``read(ctx) -> float | None``
 
@@ -35,6 +40,14 @@ HERE = Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
 
 HEALTHY = ("CONVERGED", "MAXITER")
+
+# a config's ``problem`` block: QuadraticProblem field -> the variant of
+# the problem it makes, which names the family's reference file
+PROBLEM_TERMS = {"fused_penalty": "fused", "lam": "unbalanced"}
+
+# what a geometry plugin's side may hold (``pair``/``collection`` return a
+# (relation, weights) tuple or a dict of these)
+SIDE_KEYS = ("relation", "points", "weights", "features")
 
 
 # ---------------------------------------------------------------------------
@@ -89,12 +102,38 @@ class Cell:
     @property
     def family(self):
         """The solver family's module: served answer, reference, numbers."""
-        return plugin("families", self.config["solver"]["family"])
+        return plugin("families", family_name(self.config))
 
     @property
     def loop(self):
         """The traffic's loop module: how load is offered."""
         return plugin("loops", self.traffic["loop"])
+
+
+def problem_terms(config: dict) -> dict:
+    """The config's ``problem`` block: the QuadraticProblem fields that the
+    deployment pins beyond the geometries and the loss. A key that is no
+    such field is an error, never ignored."""
+    terms = dict(config.get("problem", {}))
+    unknown = sorted(set(terms) - set(PROBLEM_TERMS))
+    if unknown:
+        raise ValueError(f"unknown problem terms {unknown}; known: "
+                         f"{sorted(PROBLEM_TERMS)}")
+    if "lam" in terms and not terms["lam"] > 0:
+        raise ValueError(f"lam must be > 0, got {terms['lam']!r}")
+    if "fused_penalty" in terms and not 0 < terms["fused_penalty"] <= 1:
+        raise ValueError(f"fused_penalty must lie in (0, 1], got "
+                         f"{terms['fused_penalty']!r}")
+    return terms
+
+
+def family_name(config: dict) -> str:
+    """The family file of a config: its ``solver.family``, followed by the
+    variant of each problem term set (``spar_gw.unbalanced``), so that a
+    balanced reference never checks a fused or unbalanced problem."""
+    terms = problem_terms(config)
+    return ".".join([config["solver"]["family"]]
+                    + [v for k, v in PROBLEM_TERMS.items() if k in terms])
 
 
 def _applies(metric: dict, cell: str) -> bool:
@@ -128,10 +167,12 @@ def load_cell(name: str, bench: Optional[dict] = None) -> Cell:
 class Traffic:
     """The requests a seed makes: geometries, the pairs of them that the
     pool holds and, for request i, the pair it solves and its PRNG seed."""
-    geoms: List[tuple]            # (relation matrix, marginal), numpy
+    geoms: List[tuple]            # (relation matrix or None, marginal)
     pairs: List[tuple]            # (x geometry, y geometry) indices
     order: np.ndarray             # pair of request i (cycled)
     key_seeds: np.ndarray         # PRNG seed of request i (cycled)
+    sides: List[dict]             # each geometry whole: SIDE_KEYS
+    problem_terms: dict           # the config's ``problem`` block
 
     def pair_of(self, i: int) -> tuple:
         return self.pairs[int(self.order[i % len(self.order)])]
@@ -140,6 +181,11 @@ class Traffic:
         """(Cx, a, Cy, b) of request i."""
         ix, iy = self.pair_of(i)
         return self.geoms[ix] + self.geoms[iy]
+
+    def geometry_data(self, i: int) -> tuple:
+        """Both sides of request i whole: dicts of SIDE_KEYS."""
+        ix, iy = self.pair_of(i)
+        return self.sides[ix], self.sides[iy]
 
     def key_seed(self, i: int) -> int:
         return int(self.key_seeds[i % len(self.key_seeds)])
@@ -152,6 +198,23 @@ ORDERS = {
 }
 
 
+def as_side(geom) -> dict:
+    """One side of a problem as a geometry plugin gives it, a (relation,
+    weights) tuple or a dict of SIDE_KEYS, as a checked dict."""
+    if isinstance(geom, tuple):
+        relation, weights = geom
+        return {"relation": relation, "weights": weights}
+    unknown = sorted(set(geom) - set(SIDE_KEYS))
+    if unknown:
+        raise ValueError(f"unknown geometry keys {unknown}; known: "
+                         f"{list(SIDE_KEYS)}")
+    if "weights" not in geom or ("relation" not in geom
+                                 and "points" not in geom):
+        raise ValueError("a geometry needs weights and a relation or "
+                         f"points; got {sorted(geom)}")
+    return dict(geom)
+
+
 def build_traffic(config: dict, traffic: dict, seed: int) -> Traffic:
     """Geometries and request order from ``seed`` alone: the config's
     geometry generator fills the traffic's pool, then the pairs are
@@ -159,15 +222,22 @@ def build_traffic(config: dict, traffic: dict, seed: int) -> Traffic:
     if traffic["order"] not in ORDERS:
         raise ValueError(f"unknown order {traffic['order']!r}; known: "
                          f"{sorted(ORDERS)}")
+    terms = problem_terms(config)
     rng = np.random.default_rng(seed)
     geometry = plugin("geometries", config["geometry"]["generator"])
     pool = plugin("pools", traffic["pool"]["kind"])
     geoms, pairs = pool.build(geometry, config["geometry"], traffic["pool"],
                               rng)
+    sides = [as_side(g) for g in geoms]
+    if {"features" in side for side in sides} - {"fused_penalty" in terms}:
+        raise ValueError("node features on every side and fused_penalty "
+                         "in the problem block go together")
     order = ORDERS[traffic["order"]](rng, len(pairs))
     key_seeds = rng.integers(0, 2**31 - 1, size=max(len(order), 4096))
-    return Traffic(geoms=geoms, pairs=pairs, order=order,
-                   key_seeds=key_seeds)
+    return Traffic(geoms=[(side.get("relation"), side["weights"])
+                          for side in sides],
+                   pairs=pairs, order=order, key_seeds=key_seeds,
+                   sides=sides, problem_terms=terms)
 
 
 def solver_fields(config: dict, n: int) -> dict:
@@ -195,9 +265,8 @@ class Client:
 
         self.cell, self.traffic = cell, traffic
         self._repro, self._jax = repro, jax
-        self.geoms = [repro.Geometry(jnp.asarray(C), jnp.asarray(w),
-                                     validate=False)
-                      for C, w in traffic.geoms]
+        self.geoms = [_geometry(repro.Geometry, jnp, side)
+                      for side in traffic.sides]
         self._family = repro.get_solver(cell.config["solver"]["family"])
         self._solvers: Dict[int, Any] = {}
 
@@ -215,10 +284,24 @@ class Client:
         ix, iy = pair
         problem = self._repro.QuadraticProblem(
             self.geoms[ix], self.geoms[iy],
-            loss=self.cell.config["solver"]["loss"], validate=False)
+            loss=self.cell.config["solver"]["loss"], validate=False,
+            **self.traffic.problem_terms)
         key = (self._jax.random.PRNGKey(key_seed)
                if getattr(self._family, "requires_key", False) else None)
         return problem, self.solver(max(problem.shape)), key
+
+
+def _geometry(Geometry, jnp, side: dict):
+    """The program's Geometry of one side: from its relation matrix, or
+    from its points where it has none; features and points ride along."""
+    arrays = {k: jnp.asarray(v) for k, v in side.items()}
+    if "relation" not in arrays:
+        return Geometry.from_points(arrays["points"], arrays["weights"],
+                                    features=arrays.get("features"),
+                                    validate=False)
+    return Geometry(arrays["relation"], arrays["weights"],
+                    features=arrays.get("features"),
+                    points=arrays.get("points"), validate=False)
 
 
 @dataclasses.dataclass

@@ -5,6 +5,9 @@ At sizes a CPU test run holds, with each cell's own limits:
 * the control -- the plain reference one precision lower (bfloat16) in
   the program's place -- fails at least one of the cell's numbers, while
   the program passes them all;
+* a config that poses more than a balanced problem (``testdata/``: an
+  unbalanced lam, fused features, point clouds) is served through the
+  harness's own ``Client``, ``warm_up`` and loop with healthy answers;
 * a whole run (``run.measure``, the chip check skipped) comes out
   ``correct`` on the program as it is and not correct with the timed
   path broken underneath: a step that returns its state unchanged, half
@@ -12,6 +15,7 @@ At sizes a CPU test run holds, with each cell's own limits:
   is produced (its value scaled by 1.05).
 """
 import dataclasses
+import json
 import sys
 import time
 from pathlib import Path
@@ -70,6 +74,70 @@ def test_control_fails_and_program_passes(name):
         answers=harness.control_answers(cell, traffic, checked))
     ok, rows = harness.judge(control, cell.limits)
     assert not ok, rows
+
+
+@pytest.mark.parametrize("name", ["moon_ugw", "moon_cloud",
+                                  "moon_fgw_cloud"])
+def test_a_config_with_a_problem_block_is_served(name, no_persistent_cache):
+    """The harness serves what a config poses with no code of its own:
+    warm-up and the closed loop go through ``Client.make``."""
+    from repro.serve import GWServer
+
+    cell = dataclasses.replace(
+        small_cell("moon_spar_n1000"), name=name, limits={},
+        config=json.loads((HERE / "testdata" / f"{name}.json").read_text()))
+    cell.traffic["pool"].update(n=24, size=2)
+    traffic = harness.build_traffic(cell.config, cell.traffic, 2**31 + 13)
+    client = harness.Client(cell, traffic)
+    server = GWServer(harness.serve_config(cell.traffic))
+    try:
+        harness.warm_up(server, client, cell)
+        _, done = cell.loop.run(server, client, cell.traffic, 0.5)
+    finally:
+        server.close()
+    assert done and not any(harness.failed(d) for d in done), [
+        (d.error, d.result and d.result.status_name) for d in done]
+    problem = client.request(0)[0]
+    terms = cell.config.get("problem", {})
+    assert problem.is_unbalanced is ("lam" in terms)
+    assert problem.is_fused is ("fused_penalty" in terms)
+    for d in done:
+        T = np.asarray(d.result.output.coupling[2])
+        assert np.all(np.isfinite(T)) and np.all(T >= 0)
+        mass = float(T.sum())
+        # a balanced coupling carries unit mass, less the tail mass of
+        # columns that the sampled support misses (about 1e-3 at n = 24);
+        # an unbalanced one is rescaled, and with lam = 1 keeps most of it
+        assert (0.5 < mass < 1.5) if "lam" in terms else \
+            abs(mass - 1.0) < 1e-2
+
+
+def test_blocked_reference_contraction_matches_whole():
+    """Past ``WHOLE_LOSS_BYTES`` (s = 32000, not 16000) the spar reference
+    contracts its support loss matrix block by block over the rows; it
+    gives what the whole product gives, to float32 rounding, also where
+    the blocks do not divide s."""
+    import jax.numpy as jnp
+
+    fam = harness.plugin("families", "spar_gw")
+    assert 4 * 16000**2 <= fam.WHOLE_LOSS_BYTES < 4 * 32000**2
+    rng = np.random.default_rng(5)
+    spec = harness.load_cell("moon_spar_n1000").config["geometry"]
+    (Cx, a), (Cy, b) = harness.plugin("geometries", "moon").pair(spec, 30,
+                                                                  rng)
+    rows, cols = rng.integers(0, 30, 200), rng.integers(0, 30, 200)
+    solver = {"epsilon": 0.01, "outer_iters": 5, "inner_iters": 20}
+    whole, blocked = (
+        fam._spar(jnp.asarray(Cx), jnp.asarray(a), jnp.asarray(Cy),
+                  jnp.asarray(b), jnp.asarray(rows, jnp.int32),
+                  jnp.asarray(cols, jnp.int32), solver["epsilon"], m=30,
+                  n=30, outer=5, inner=20, block=64, blocked=flag,
+                  dtype=jnp.float32) for flag in (False, True))
+    assert float(whole[0]) > 0
+    np.testing.assert_allclose(float(blocked[0]), float(whole[0]),
+                               rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(blocked[1]), np.asarray(whole[1]),
+                               rtol=1e-4, atol=1e-7)
 
 
 def _wrap_exec(monkeypatch, alter):

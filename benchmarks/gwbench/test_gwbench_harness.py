@@ -1,7 +1,9 @@
 """Tests of the benchmark harness that need no chip: discovery by name,
-the limits of ``BENCHMARK.json``, generators, the closed loop, the work
-count, the trace reduction, the peaks table, and the command's refusal
-off the TPU."""
+the limits of ``BENCHMARK.json``, generators, the problems a config poses,
+the closed loop, the work count, the trace reduction, the peaks table,
+and the command's refusal off the TPU."""
+import dataclasses
+import hashlib
 import json
 import os
 import re
@@ -210,6 +212,164 @@ def test_graph_sizes_follow_the_configuration():
     assert abs(edges - c["edges_mean"]) < 0.5
     for A, _ in g:           # connected: every node has an edge
         assert np.all(A.sum(1) > 0)
+
+
+# -- the problem a configuration poses ----------------------------------------
+
+def _small(cell: str):
+    c = harness.load_cell(cell)
+    if "n" in c.traffic["pool"]:
+        c.traffic["pool"].update(n=40, size=3)
+    else:
+        c.config["geometry"].update(count=12)
+    return c
+
+
+def _testdata_cell(name: str, n: int = 24):
+    """A cell of the test config ``testdata/<name>.json`` on the closed
+    two-client Moon traffic, at n points a side."""
+    base = harness.load_cell("moon_spar_n1000")
+    base.traffic["pool"].update(n=n, size=2)
+    config = json.loads((HERE / "testdata" / f"{name}.json").read_text())
+    return dataclasses.replace(base, name=name, config=config, limits={})
+
+
+# sha256 of each cell's traffic at seed 2**31 + 5 (geometries, pairs,
+# order, key seeds), at the sizes of ``_small``, as the benchmark made it
+# before a config could carry a problem block
+TRAFFIC_DIGESTS = {
+    "moon_spar_n1000":
+        "ee718d9786fc321725f38929eebe10f41e636402e91dc2c6ddc139ac10cbf6f8",
+    "moon_spar_n500":
+        "ee718d9786fc321725f38929eebe10f41e636402e91dc2c6ddc139ac10cbf6f8",
+    "mutag_allpairs":
+        "7d06c52a1fa2344a3f0edee9cabc7053e53e19b318ba502f07bed51d2164989a",
+}
+
+
+@pytest.mark.parametrize("cell", sorted(TRAFFIC_DIGESTS))
+def test_existing_traffic_is_unchanged(cell):
+    c = _small(cell)
+    t = harness.build_traffic(c.config, c.traffic, 2**31 + 5)
+    h = hashlib.sha256()
+    for C, w in t.geoms:
+        h.update(C.tobytes())
+        h.update(w.tobytes())
+    h.update(repr(t.pairs).encode())
+    h.update(t.order.tobytes())
+    h.update(t.key_seeds.tobytes())
+    assert h.hexdigest() == TRAFFIC_DIGESTS[cell]
+    assert t.problem_terms == {}
+    assert [(s["relation"], s["weights"]) for s in t.sides] == t.geoms
+
+
+@pytest.mark.parametrize("cell", ["moon_spar_n1000", "mutag_allpairs"])
+def test_existing_configs_build_bitwise_the_same_problems(cell):
+    """``Client.make`` gives what it gave before a config could pose more
+    than a balanced problem: ``QuadraticProblem(Geometry(C, w),
+    Geometry(C', w'), loss=...)``, the same treedef and the same leaves."""
+    import jax
+    import jax.numpy as jnp
+
+    harness.add_src_to_path()
+    import repro
+
+    c = _small(cell)
+    t = harness.build_traffic(c.config, c.traffic, 2**31 + 5)
+    client = harness.Client(c, t)
+    for i in range(len(t.pairs)):
+        problem, solver, key = client.request(i)
+        (Cx, a), (Cy, b) = (t.geoms[j] for j in t.pair_of(i))
+        want = repro.QuadraticProblem(
+            repro.Geometry(jnp.asarray(Cx), jnp.asarray(a), validate=False),
+            repro.Geometry(jnp.asarray(Cy), jnp.asarray(b), validate=False),
+            loss=c.config["solver"]["loss"], validate=False)
+        got_leaves, got_def = jax.tree.flatten(problem)
+        want_leaves, want_def = jax.tree.flatten(want)
+        assert got_def == want_def
+        assert len(got_leaves) == len(want_leaves) == 4
+        for x, y in zip(got_leaves, want_leaves):
+            assert x.dtype == y.dtype and np.array_equal(x, y)
+        assert not (problem.is_unbalanced or problem.is_fused
+                    or problem.geom_x.is_point_cloud)
+        assert solver == repro.get_solver(c.config["solver"]["family"])(
+            **harness.solver_fields(c.config, max(problem.shape)))
+        if key is not None:
+            assert np.array_equal(key, jax.random.PRNGKey(t.key_seed(i)))
+
+
+@pytest.mark.parametrize("name,unbalanced,fused,points", [
+    ("moon_ugw", True, False, False),
+    ("moon_cloud", False, False, True),
+    ("moon_fgw_cloud", False, True, True),
+])
+def test_a_config_poses_its_whole_problem(name, unbalanced, fused, points):
+    harness.add_src_to_path()
+    c = _testdata_cell(name)
+    t = harness.build_traffic(c.config, c.traffic, 2**31 + 7)
+    assert t.problem_terms == c.config.get("problem", {})
+    x, y = t.geometry_data(0)
+    assert ("points" in x) is points and ("features" in y) is fused
+    problem, _, _ = harness.Client(c, t).request(0)
+    assert problem.is_unbalanced is unbalanced
+    assert problem.is_fused is fused
+    assert problem.geom_x.is_point_cloud is points
+    assert problem.geom_y.is_point_cloud is points
+    if unbalanced:
+        assert problem.lam == c.config["problem"]["lam"]
+    if fused:
+        assert problem.fused_penalty == c.config["problem"]["fused_penalty"]
+        assert problem.geom_x.features.shape == (24, 5)
+    if points:
+        assert problem.geom_x.cost is None
+        assert np.array_equal(problem.geom_x.points, x["points"])
+    else:
+        assert t.problem_data(0)[0] is x["relation"]
+    problem.check()      # what the program itself accepts
+
+
+@pytest.mark.parametrize("name,family", [
+    ("moon_ugw", "spar_gw.unbalanced"),
+    ("moon_cloud", "spar_gw"),
+    ("moon_fgw_cloud", "spar_gw.fused"),
+])
+def test_family_file_follows_the_variant_of_the_problem(name, family):
+    c = _testdata_cell(name)
+    assert harness.family_name(c.config) == family
+    c.config["problem"] = {"lam": 0.5, "fused_penalty": 0.5}
+    assert harness.family_name(c.config) == "spar_gw.fused.unbalanced"
+
+
+@pytest.mark.parametrize("problem,match", [
+    ({"lambda": 1.0}, "unknown problem terms"),
+    ({"M": [[0.0]]}, "unknown problem terms"),
+    ({"lam": 0.0}, "lam must be > 0"),
+    ({"fused_penalty": 1.5}, "fused_penalty must lie in"),
+    ({"fused_penalty": 0.5}, "go together"),
+])
+def test_a_bad_problem_block_is_refused(problem, match):
+    c = _testdata_cell("moon_ugw")
+    c.config["problem"] = problem
+    with pytest.raises(ValueError, match=match):
+        harness.build_traffic(c.config, c.traffic, 1)
+
+
+def test_features_without_fused_penalty_are_refused():
+    c = _testdata_cell("moon_fgw_cloud")
+    del c.config["problem"]
+    with pytest.raises(ValueError, match="go together"):
+        harness.build_traffic(c.config, c.traffic, 1)
+
+
+@pytest.mark.parametrize("side,match", [
+    ({"relation": np.eye(2), "weights": np.ones(2) / 2, "labels": 1},
+     "unknown geometry keys"),
+    ({"relation": np.eye(2)}, "needs weights"),
+    ({"weights": np.ones(2) / 2, "features": np.eye(2)}, "needs weights"),
+])
+def test_a_bad_geometry_side_is_refused(side, match):
+    with pytest.raises(ValueError, match=match):
+        harness.as_side(side)
 
 
 # -- the closed loop ----------------------------------------------------------
