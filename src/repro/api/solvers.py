@@ -251,19 +251,22 @@ class SparGWSolver:
         m, n = a.shape[0], b.shape[0]
         scale = jnp.sqrt(jnp.sum(a) * jnp.sum(b))
 
-        # steps 2-3: dense rank-one init and its (log-)kernel — computed once
-        Td = a[:, None] * b[None, :] / scale
-        m0 = jnp.sum(Td)
-        C0 = dense_cost(Cx, Cy, Td, loss) + _marginal_penalty(
-            Td.sum(1), Td.sum(0), a, b, lam)
-        logK0 = -C0 / (eps * m0) + jnp.log(jnp.maximum(Td, 1e-38))
+        with jax.named_scope("gw.ugw_init"):
+            # steps 2-3: dense rank-one init and its (log-)kernel — once
+            Td = a[:, None] * b[None, :] / scale
+            m0 = jnp.sum(Td)
+            # HIGHEST: one bfloat16 pass on a TPU would move the eq.-9 law
+            with jax.default_matmul_precision("highest"):
+                C0 = dense_cost(Cx, Cy, Td, loss)
+            C0 = C0 + _marginal_penalty(Td.sum(1), Td.sum(0), a, b, lam)
+            logK0 = -C0 / (eps * m0) + jnp.log(jnp.maximum(Td, 1e-38))
 
-        # steps 4-5: sampling probability (eq. 9) and index set
-        P = sampling.unbalanced_probs(a, b, logK0, lam, eps, self.shrink)
-        rows, cols = sampling.sample_pairs_2d(key, P, self.s)
-        p = P[rows, cols]
-        logw = -jnp.log(self.s * jnp.maximum(p, 1e-38))
-        T0 = a[rows] * b[cols] / scale
+            # steps 4-5: sampling probability (eq. 9) and index set
+            P = sampling.unbalanced_probs(a, b, logK0, lam, eps, self.shrink)
+            rows, cols = sampling.sample_pairs_2d(key, P, self.s)
+            p = P[rows, cols]
+            logw = -jnp.log(self.s * jnp.maximum(p, 1e-38))
+            T0 = a[rows] * b[cols] / scale
         cost_fn = make_spar_cost_fn(Cx, Cy, rows, cols, loss,
                                     impl=self.cost_impl, chunk=self.cost_chunk)
 

@@ -73,9 +73,19 @@ def unbalanced_probs(a, b, logK, lam: float, eps: float, shrink: float = 0.0):
 
 
 def sample_pairs_2d(key, P, s: int):
-    """s i.i.d. index pairs from a dense 2-D probability matrix."""
+    """s i.i.d. index pairs from a dense 2-D probability matrix.
+
+    The draws of ``jax.random.choice(key, m * n, (s,), p=P.reshape(-1))``
+    (inverse transform of the same uniforms over the row-major CDF), with
+    the CDF summed per row and then over the row totals: for a TPU v5e one
+    cumulative sum over all 1024² cells compiles in ~25 s, these in ~1 s.
+    """
     m, n = P.shape
-    flat = jax.random.choice(key, m * n, (s,), p=P.reshape(-1))
+    row_cdf = jnp.cumsum(P, axis=1)
+    row_start = jnp.cumsum(row_cdf[:, -1]) - row_cdf[:, -1]
+    cdf = (row_cdf + row_start[:, None]).reshape(-1)
+    r = cdf[-1] * (1 - jax.random.uniform(key, (s,), dtype=cdf.dtype))
+    flat = jnp.searchsorted(cdf, r).astype(jnp.int32)
     return flat // n, flat % n
 
 

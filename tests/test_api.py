@@ -1,5 +1,6 @@
 """Unified Problem/Solver/Output API: pytree round-trips, validation,
 jit+vmap batched solves, shim equivalence, registry, early stopping."""
+import re
 import warnings
 
 import jax
@@ -453,3 +454,23 @@ def test_fused_features_derive_linear_term():
     o1 = solve(p_feat, SparGWSolver(s=4 * N, **FAST), key=KEY)
     o2 = solve(p_M, SparGWSolver(s=4 * N, **FAST), key=KEY)
     np.testing.assert_allclose(float(o1.value), float(o2.value), rtol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# SPAR-UGW start
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("loss", ["l2", "kl", "l1"])
+def test_unbalanced_start_cost_runs_at_highest(loss):
+    """Alg. 3's start cost C(T⁰) sets the eq.-9 sampling law, so every
+    product outside the per-step cost (``gw.cost``) is lowered at
+    HIGHEST: at default precision a TPU rounds it to one bfloat16 pass."""
+    prob = _problem(loss=loss, lam=1.0)
+    text = jax.jit(lambda p: solve(p, SparGWSolver(s=4 * N, **FAST),
+                                   key=KEY).value).lower(prob).as_text(
+                                       debug_info=True)
+    locs = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    dots = re.findall(r"stablehlo\.dot_general .*precision = \[(\w+), \w+\]"
+                      r".* loc\((#loc\d+)\)", text)
+    start = [prec for prec, loc in dots if "gw.cost" not in locs.get(loc, "")]
+    assert start and all(prec == "HIGHEST" for prec in start), dots

@@ -130,3 +130,18 @@ def test_sample_pairs_2d_duplicates_match_flat_probs():
     freq = np.zeros((n, n))
     np.add.at(freq, (np.asarray(rows), np.asarray(cols)), 1.0 / 1000)
     np.testing.assert_allclose(freq, np.asarray(P), atol=0.05)
+
+
+def test_sample_pairs_2d_draws_as_choice_over_the_flat_law():
+    """The 2-D draw is ``jax.random.choice`` over the row-major law: the
+    same key gives the same cells, but for a draw whose point falls
+    within float32 rounding of a cell's edge (the CDF is summed per row,
+    then over the row totals)."""
+    m, n, s = 64, 48, 5000
+    for seed in range(4):
+        kp, kd = jax.random.split(jax.random.PRNGKey(seed))
+        P = jnp.exp(3.0 * jax.random.normal(kp, (m, n)))
+        P = P / P.sum()
+        rows, cols = sampling.sample_pairs_2d(kd, P, s)
+        flat = jax.random.choice(kd, m * n, (s,), p=P.reshape(-1))
+        assert int(jnp.sum(rows * n + cols != flat)) <= s // 1000

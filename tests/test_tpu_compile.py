@@ -85,9 +85,10 @@ def test_fused_spar_cost_compiles(one_chip, m, s_p):
     assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
-def _served_spar_batch(sharding, n, bucket, impl, lanes=2):
+def _served_spar_batch(sharding, n, bucket, impl, lanes=2, lam=None):
     """GWServer's compiled executable for a spar_gw bucket of ``lanes``
-    Gaussian point clouds of n points, s = 16n, on cost_impl ``impl``."""
+    Gaussian point clouds of n points, s = 16n, on cost_impl ``impl``;
+    unbalanced (Algorithm 3) where ``lam`` is given."""
     import repro
     from repro.serve import GWServer, ServeConfig
     from repro.serve.batching import pad_problem
@@ -95,7 +96,9 @@ def _served_spar_batch(sharding, n, bucket, impl, lanes=2):
     pts = np.random.default_rng(0).standard_normal((n, 2)).astype(np.float32)
     C = np.sqrt(((pts[:, None] - pts[None]) ** 2).sum(-1))
     geom = repro.Geometry(jnp.asarray(C), jnp.full(n, 1.0 / n, jnp.float32))
-    problem = pad_problem(repro.QuadraticProblem(geom, geom), bucket, bucket)
+    terms = {} if lam is None else {"lam": lam}
+    problem = pad_problem(repro.QuadraticProblem(geom, geom, **terms),
+                          bucket, bucket)
     solver = repro.SparGWSolver(s=16 * n, cost_impl=impl)
     item = (problem, solver, jax.random.PRNGKey(0))
     # the shapes ``stack_items`` gives, without stacking the lanes
@@ -134,12 +137,19 @@ def test_served_spar_batch_keeps_kernel_names_and_scopes(one_chip, kernel,
     instruction named after the Pallas kernel, and the three named scopes
     in the HLO ``op_name`` metadata."""
     monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
-    text = _served_spar_batch(one_chip, *SERVED[kernel]).as_text()
+    _assert_kernel_and_scopes(
+        _served_spar_batch(one_chip, *SERVED[kernel]).as_text(), kernel,
+        SCOPES)
+
+
+def _assert_kernel_and_scopes(text, kernel, scopes):
+    """Every Pallas call in the compiled text is named after ``kernel``,
+    and each scope is in some op's ``op_name`` metadata."""
     names = re.findall(r"^\s*(?:ROOT )?%?([\w.-]+) = .*custom_call_target="
                        r"\"tpu_custom_call\"", text, re.M)
     assert names and all(kernel in name for name in names), names
     op_names = re.findall(r'op_name="([^"]*)"', text)
-    for scope in SCOPES:
+    for scope in scopes:
         assert any(scope in o for o in op_names), scope
 
 
@@ -184,8 +194,12 @@ def test_served_spar_inner_sinkhorn_loop_has_no_scatter(one_chip, kernel,
     step (a scatter onto the 2 · bucket² cells)."""
     monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
     n, bucket, impl = SERVED[kernel]
-    comps = _computations(
-        _served_spar_batch(one_chip, n, bucket, impl).as_text())
+    _assert_inner_loop_has_no_scatter(
+        _served_spar_batch(one_chip, n, bucket, impl).as_text(), bucket)
+
+
+def _assert_inner_loop_has_no_scatter(text, bucket):
+    comps = _computations(text)
     loops = {}      # while instruction -> (computation holding it, body)
     for name, lines in comps.items():
         for line in lines:
@@ -210,6 +224,23 @@ def test_served_spar_inner_sinkhorn_loop_has_no_scatter(one_chip, kernel,
     assert 2 * bucket * bucket in {
         int(np.prod([int(d) for d in g.group(1).split(",")]))
         for g in grids if g}
+
+
+def test_served_ugw_batch_compiles_with_its_scopes(one_chip, monkeypatch):
+    """GWServer's executable for a 2-lane unbalanced spar_gw bucket
+    (Algorithm 3) at n = 1000, bucket 1024, as the ``moon_ugw_n1000``
+    cell serves it: the gather-fused cost kernel and the ``gw.ugw_init``
+    (start, eq.-9 law, 2-D draw, weights), ``gw.cost`` and
+    ``gw.sinkhorn`` scopes survive into the compiled text; the inner
+    Sinkhorn loop runs on the dense grid as in the balanced case; the
+    batch fits the chip."""
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    compiled = _served_spar_batch(one_chip, 1000, 1024, "pallas", lam=1.0)
+    text = compiled.as_text()
+    _assert_kernel_and_scopes(text, "spar_cost_pallas",
+                              ("gw.ugw_init", "gw.cost", "gw.sinkhorn"))
+    _assert_inner_loop_has_no_scatter(text, 1024)
+    assert _peak_bytes(compiled) < V5E_HBM_BYTES
 
 
 def _peak_bytes(compiled):
