@@ -6,8 +6,8 @@ Three interchangeable implementations of the affine contract
 - ``"jnp"``          — row-chunked ``lax.map`` oracle (ref.py). Gathers the
                        (chunk, s) support blocks from HBM every call.
 - ``"pallas"``       — gather-fused Pallas kernel; O(s·(m+n)) resident
-                       transposed row panels, per-tile gathers are VMEM
-                       row loads.
+                       sublane-dense transposed row panels, per-tile
+                       gathers are VMEM tile loads.
 - ``"materialized"`` — iteration-invariant loss matrix hoisted once
                        (O(s²) HBM, budget-gated); every call is a single
                        fused matvec + epilogue with zero gathers.
@@ -27,12 +27,17 @@ import jax.numpy as jnp
 from repro.kernels import dispatch
 from repro.kernels.spar_cost.ref import materialize_loss, spar_cost_ref
 from repro.kernels.spar_cost.spar_cost import (
+    K_BLOCK,
+    L_BLOCK,
+    row_panels,
     spar_cost_pallas,
     spar_matvec_pallas,
 )
 
+# the materialized matvec's blocks; the fused kernel's k-block is
+# ``K_BLOCK``
 dispatch.register("spar_cost", default_block=256,
-                  description="fused COO cost assembly (SPAR-GW hot path)")
+                  description="COO cost assembly (SPAR-GW hot path)")
 
 
 def resolve_impl(impl: str, s: int) -> str:
@@ -45,33 +50,40 @@ def resolve_impl(impl: str, s: int) -> str:
 
 
 def _fused_setup(Cx, Cy, rows, cols, block: Optional[int]):
-    """Block size, padded size and the kernel's transposed row panels."""
-    s = rows.shape[0]
-    b = dispatch.block_size("spar_cost", block, cap=s)
-    s_p = -(-s // b) * b
-    rows_p = dispatch.pad_dim(rows.astype(jnp.int32), b)
-    cols_p = dispatch.pad_dim(cols.astype(jnp.int32), b)
-    return b, s_p, rows_p, cols_p, Cx[rows_p].T, Cy[cols_p].T
+    """The support padded for the fused kernel's l-walk (to ``L_BLOCK``),
+    and its sublane-dense row panels over the support padded to the
+    k-block (``block`` where given, else ``K_BLOCK``)."""
+    bk = block or K_BLOCK
+    rows, cols = rows.astype(jnp.int32), cols.astype(jnp.int32)
+    XT = row_panels(Cx, dispatch.pad_dim(rows, bk), bk)
+    YT = row_panels(Cy, dispatch.pad_dim(cols, bk), bk)
+    return (dispatch.pad_dim(rows, L_BLOCK), dispatch.pad_dim(cols, L_BLOCK),
+            XT, YT)
 
 
 def _vec(x, s_p: int):
-    """Broadcast a scalar / (s,) offset to a zero-padded (s_p,) float32."""
+    """Broadcast a scalar / (s,) vector to a zero-padded (s_p,) float32."""
     x = jnp.broadcast_to(jnp.asarray(x, jnp.float32),
                          (s_p,) if jnp.ndim(x) == 0 else jnp.shape(x))
     return dispatch.pad_dim(x, s_p) if x.shape[0] != s_p else x
 
 
+def _fused_call(setup, t, off, loss: str, interpret: bool):
+    """L-matvec(t) + off on the support of ``_fused_setup``, (s_k,)."""
+    rows_l, cols_l, XT, YT = setup
+    s_k = XT.shape[0] * XT.shape[2] * XT.shape[3]
+    return spar_cost_pallas(XT, YT, rows_l, cols_l, _vec(t, rows_l.shape[0]),
+                            _vec(off, s_k), loss=loss, interpret=interpret)
+
+
 def spar_cost_fused(Cx, Cy, rows, cols, t, off=0.0, loss: str = "l2",
                     block: Optional[int] = None,
                     interpret: Optional[bool] = None):
-    """One-shot gather-fused cost: L @ t + off on the COO support, (s,)."""
-    s = rows.shape[0]
-    b, s_p, rows_p, cols_p, XT, YT = _fused_setup(Cx, Cy, rows, cols, block)
-    out = spar_cost_pallas(XT, YT, rows_p, cols_p,
-                           _vec(t, s_p), _vec(off, s_p), loss=loss,
-                           bk=b, bl=b,
-                           interpret=dispatch.interpret_mode(interpret))
-    return out[:s]
+    """One-shot gather-fused cost: L @ t + off on the COO support, (s,).
+    ``block`` sets the k-block (default: ``K_BLOCK``)."""
+    setup = _fused_setup(Cx, Cy, rows, cols, block)
+    out = _fused_call(setup, t, off, loss, dispatch.interpret_mode(interpret))
+    return out[:rows.shape[0]]
 
 
 def spar_matvec(Lmat, t, off=0.0, block: Optional[int] = None,
@@ -120,15 +132,11 @@ def _cost_fn(Cx, Cy, rows, cols, loss: str, impl: str, chunk: int,
         return fn
 
     if impl == "pallas":
-        b, s_p, rows_p, cols_p, XT, YT = _fused_setup(Cx, Cy, rows, cols,
-                                                      block)
+        setup = _fused_setup(Cx, Cy, rows, cols, block)
         itp = dispatch.interpret_mode(interpret)
 
         def fn(t, off=0.0):
-            out = spar_cost_pallas(XT, YT, rows_p, cols_p,
-                                   _vec(t, s_p), _vec(off, s_p), loss=loss,
-                                   bk=b, bl=b, interpret=itp)
-            return out[:s]
+            return _fused_call(setup, t, off, loss, itp)[:s]
         return fn
 
     if impl == "materialized":

@@ -15,7 +15,14 @@ from repro.kernels.spar_cost.ops import (
     spar_cost_fused,
     spar_matvec,
 )
+from repro.kernels.spar_cost.ops import _fused_setup
 from repro.kernels.spar_cost.ref import materialize_loss, spar_cost_ref
+from repro.kernels.spar_cost.spar_cost import (
+    K_BLOCK,
+    _vmem_limit,
+    row_panels,
+    spar_cost_pallas,
+)
 
 KEY = jax.random.PRNGKey(0)
 
@@ -43,6 +50,88 @@ def test_fused_kernel_matches_oracle(loss, s):
                           interpret=True)
     np.testing.assert_allclose(np.array(got), np.array(ref), rtol=1e-4,
                                atol=1e-5)
+
+
+# the k-blocks the chip takes: (8, 128) and (8, 256) panel tiles; s is a
+# multiple of neither block, nor of the 256 l-block
+@pytest.mark.parametrize("loss", ["l1", "l2", "kl"])
+@pytest.mark.parametrize("bk", [1024, 2048])
+def test_fused_kernel_chip_k_blocks_match_oracle(loss, bk):
+    s = 2500
+    Cx, Cy, rows, cols, t = _support(40, 70, s, seed=bk)
+    off = jax.random.normal(jax.random.PRNGKey(bk + 1), (s,))
+    ref = spar_cost_ref(Cx, Cy, rows, cols, t, loss, chunk=512) + off
+    got = spar_cost_fused(Cx, Cy, rows, cols, t, off, loss=loss, block=bk,
+                          interpret=True)
+    np.testing.assert_allclose(np.array(got), np.array(ref), rtol=1e-4,
+                               atol=1e-5)
+
+
+@pytest.mark.parametrize("bk", [1024, 2048])
+def test_fused_kernel_chip_k_blocks_duplicate_pairs(bk):
+    s = 1100
+    Cx, Cy, _, _, t = _support(30, 30, s, seed=9)
+    rows = jax.random.randint(KEY, (s,), 0, 30)
+    cols = rows[::-1]
+    rows = rows.at[100:400].set(rows[0])                # repeated pairs
+    cols = cols.at[100:400].set(cols[0])
+    for loss in ("l1", "l2", "kl"):
+        ref = spar_cost_ref(Cx, Cy, rows, cols, t, loss, chunk=512)
+        got = spar_cost_fused(Cx, Cy, rows, cols, t, loss=loss, block=bk,
+                              interpret=True)
+        np.testing.assert_allclose(np.array(got), np.array(ref), rtol=1e-4,
+                                   atol=1e-5)
+
+
+def test_row_panels_are_sublane_dense_transposed_rows():
+    C = jnp.arange(5 * 5, dtype=jnp.float32).reshape(5, 5)
+    idx = jax.random.randint(KEY, (32,), 0, 5)
+    P = np.array(row_panels(C, idx, 16))
+    assert P.shape == (2, 5, 8, 2)
+    for k in range(32):
+        kb, a, b = k // 16, (k % 16) // 2, k % 2
+        np.testing.assert_array_equal(P[kb, :, a, b], np.array(C[idx[k]]))
+
+
+def test_fused_setup_pads_k_and_l_apart():
+    """k pads to the k-block, the l-walk only to the 256 l-block: padding
+    k to 2048 adds no l-steps."""
+    s = 2500
+    Cx, Cy, rows, cols, _ = _support(40, 70, s)
+    rows_l, cols_l, XT, YT = _fused_setup(Cx, Cy, rows, cols, 2048)
+    assert rows_l.shape == cols_l.shape == (2560,)
+    assert XT.shape == (2, 40, 8, 256) and YT.shape == (2, 70, 8, 256)
+
+
+VMEM_BYTES = 128 * 2**20             # one v5e TensorCore's VMEM
+
+
+# the served buckets, and 4096, the largest whose panels fit VMEM
+@pytest.mark.parametrize("bucket", [512, 1024, 2048, 4096])
+def test_k_block_panels_fit_vmem_by_bucket(bucket):
+    limit = _vmem_limit(bucket, bucket, K_BLOCK)
+    # both panels' k-blocks, double-buffered, fit under the limit, and the
+    # limit under the chip's VMEM
+    assert 2 * 2 * bucket * K_BLOCK * 4 < limit < VMEM_BYTES
+
+
+# compiled for the chip, a k-block must fill whole (8, 128) tiles and its
+# panels must fit VMEM: 2048 at bucket 4096 does not, nor 1024 at 8192,
+# nor 512 anywhere
+@pytest.mark.parametrize("m,bk", [(4096, 2048), (8192, 1024), (64, 512)])
+def test_fused_kernel_refuses_chip_k_blocks_it_cannot_hold(m, bk):
+    panel = jax.ShapeDtypeStruct((1, m, 8, bk // 8), jnp.float32)
+    vec = jax.ShapeDtypeStruct((256,), jnp.float32)
+    with pytest.raises(ValueError, match="k-block"):
+        spar_cost_pallas.lower(panel, panel, vec.update(dtype=jnp.int32),
+                               vec.update(dtype=jnp.int32), vec,
+                               jax.ShapeDtypeStruct((bk,), jnp.float32),
+                               interpret=False)
+
+
+def test_row_panels_refuse_k_block_off_the_sublanes():
+    with pytest.raises(ValueError, match="multiple of 8"):
+        row_panels(jnp.eye(4), jnp.zeros(12, jnp.int32), 12)
 
 
 @pytest.mark.parametrize("loss", ["l1", "l2", "kl"])

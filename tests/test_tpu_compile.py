@@ -69,19 +69,25 @@ def test_spar_matvec_compiles(one_chip):
     _assert_kernel(compiled)
 
 
-# the server's shapes: bucket 1024 with s = 16n at n = 1000 (padded to a
-# multiple of the 256 block), and bucket 2048 with s = 16 · 2048
-@pytest.mark.parametrize("m,s_p", [(1024, 16128), (2048, 32768)])
-def test_fused_spar_cost_compiles(one_chip, m, s_p):
-    from repro.kernels.spar_cost.spar_cost import spar_cost_pallas
+# the server's shapes: bucket 1024 with s = 16n at n = 1000, and bucket
+# 2048 with s = 16n at n = 2000; and bucket 4096 at n = 4000, the largest
+# whose panels fit VMEM
+@pytest.mark.parametrize("m,s", [(1024, 16000), (2048, 32000),
+                                 (4096, 64000)])
+def test_fused_spar_cost_compiles(one_chip, m, s):
+    from repro.kernels.spar_cost.spar_cost import (
+        K_BLOCK as bk, L_BLOCK, _vmem_limit, spar_cost_pallas)
+    s_k, s_l = -(-s // bk) * bk, -(-s // L_BLOCK) * L_BLOCK
+    panel = _spec(one_chip, (s_k // bk, m, 8, bk // 8))
     compiled = spar_cost_pallas.lower(
-        _spec(one_chip, (m, s_p)), _spec(one_chip, (m, s_p)),
-        _spec(one_chip, (s_p,), jnp.int32), _spec(one_chip, (s_p,), jnp.int32),
-        _spec(one_chip, (s_p,)), _spec(one_chip, (s_p,)),
-        loss="l2", bk=256, bl=256, interpret=False).compile()
+        panel, panel, _spec(one_chip, (s_l,), jnp.int32),
+        _spec(one_chip, (s_l,), jnp.int32), _spec(one_chip, (s_l,)),
+        _spec(one_chip, (s_k,)), loss="l2", interpret=False).compile()
     _assert_kernel(compiled)
-    # resident panels, double-buffered: 2 · 2 · m · 256 · 4 B, within the
-    # default scoped VMEM; the kernel adds no HBM temporaries of its own
+    # resident panels, double-buffered: 2 · 2 · m · bk · 4 B, past Mosaic's
+    # default scoped VMEM, so the kernel states its limit, within the
+    # chip's 128 MiB of VMEM; the kernel adds no HBM temporaries of its own
+    assert 2 * 2 * m * bk * 4 < _vmem_limit(m, m, bk) < 128 * 2**20
     assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
