@@ -84,8 +84,8 @@ def run_solver_mode(names, n: int, loss: str, reps: int,
 
 _SUITE = ("bench_fig2", "bench_fig3_ugw", "bench_fig4_sensitivity",
           "bench_fig5_scaling", "bench_fig6_fgw", "bench_grid_vs_coo",
-          "bench_spar_cost", "bench_tables23_graphs", "bench_multiscale",
-          "bench_lowrank", "bench_lm_step", "bench_serve", "bench_diff")
+          "bench_tables23_graphs", "bench_multiscale", "bench_lowrank",
+          "bench_serve", "bench_diff")
 
 
 def run_full_suite() -> None:
@@ -99,13 +99,6 @@ def run_full_suite() -> None:
         except Exception:  # noqa: BLE001
             traceback.print_exc()
             failures.append(name)
-    # roofline table (reads dry-run artifacts if present)
-    try:
-        from benchmarks import roofline
-        roofline.main()
-    except Exception:  # noqa: BLE001
-        traceback.print_exc()
-        failures.append("roofline")
     if failures:
         print("FAILED:", failures, file=sys.stderr)
         raise SystemExit(1)

@@ -1,7 +1,7 @@
 """Architecture / shape / run configuration dataclasses.
 
 Every assigned architecture gets a module ``repro/configs/<id>.py`` exporting
-``CONFIG`` (full-size, dry-run only) and ``reduced()`` (CPU smoke-test size).
+``CONFIG`` (full-size) and ``reduced()`` (CPU smoke-test size).
 """
 from __future__ import annotations
 

@@ -9,9 +9,8 @@ assembly + Sinkhorn on the s_r × s_c grid block) shard as:
 
 Cost assembly (decomposable L) is a distributed matmul chain; Sinkhorn
 matvecs psum over the opposing axis. Everything is ``shard_map`` with
-explicit collectives, so the collective schedule is visible to the
-roofline (benchmarks/bench_gw_dryrun.py dry-runs this exact program on the
-production mesh).
+explicit collectives, so the collective schedule is visible in the
+compiled program.
 """
 from __future__ import annotations
 
@@ -45,12 +44,11 @@ def make_sharded_grid_gw(mesh: Mesh, s_r: int, s_c: int, loss: str = "l2",
 
     Decomposable-loss path (the ``l2`` production configuration).
 
-    Hillclimb lever (EXPERIMENTS.md §Perf):
-    · ``comm_dtype=jnp.bfloat16`` — cast large gathers to bf16 on the wire.
+    ``comm_dtype=jnp.bfloat16`` casts large gathers to bf16 on the wire.
     (A psum-of-partials h-term restructure was tried and is *invalid* here:
     both contraction and output dims of each hop live on the same mesh
     axis, so partials from different devices cover different output blocks
-    — caught by the 4-device equivalence test; see §Perf iteration log.)
+    — caught by the 4-device equivalence test.)
     """
     dec = gc.get_decomposition(loss)
     assert dec is not None, "sharded path implements decomposable costs"

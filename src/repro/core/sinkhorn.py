@@ -303,7 +303,7 @@ def _sparse_log_layout(m: int, n: int):
     decide whether a batch fits (bucket 16384, 3 lanes: 20.0 GiB, over
     the chip's 15.75, against 12.2). Counts each choice in
     ``repro_sinkhorn_layout_total``: under jit that is once per trace,
-    not per execution (as ``dispatch.block_size``).
+    not per execution.
     """
     layout = "dense" if m * n <= _DENSE_CELLS_MAX else "coo"
     _obs_registry().counter(

@@ -1,7 +1,7 @@
 """GPipe-style pipeline parallelism over a ``pipe`` mesh axis.
 
 Provided as a composable module (tested on a multi-device host mesh). The
-production 40-cell dry-run uses DP/FSDP/TP/EP meshes per the assignment —
+sharding rules use DP/FSDP/TP/EP meshes —
 on TPU ICI those dominate PP (MaxText practice); PP becomes relevant on
 DCN-linked superpods, where this schedule applies across the `pipe` axis.
 

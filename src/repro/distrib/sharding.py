@@ -2,7 +2,7 @@
 
 Every parameter carries a tuple of logical axis names (see models/module.py);
 this module maps them to ``PartitionSpec``s for a concrete mesh. Rules are a
-plain dict so per-arch hillclimbing can override them (EXPERIMENTS.md §Perf).
+plain dict, so a caller can override one per architecture.
 
 Divisibility guard: a mesh axis is only assigned when it evenly divides the
 dimension — otherwise the dim falls back to replication. This is what makes

@@ -6,8 +6,8 @@ from typing import Optional
 from repro.kernels import dispatch
 from repro.kernels.flash_attention.flash_attention import flash_attention_pallas
 
-dispatch.register("flash_attention", default_block=128,
-                  description="causal GQA flash attention (online softmax)")
+# query and key blocks, each capped at the sequence length
+BLOCK = 128
 
 
 def flash_attention(q, k, v, causal: bool = True, bq: Optional[int] = None,
@@ -18,8 +18,8 @@ def flash_attention(q, k, v, causal: bool = True, bq: Optional[int] = None,
     B, S, H, hd = q.shape
     K = k.shape[2]
     G = H // K
-    bq = dispatch.block_size("flash_attention", bq, cap=S)
-    bk = dispatch.block_size("flash_attention", bk, cap=S)
+    bq = min(bq or BLOCK, S)
+    bk = min(bk or BLOCK, S)
     # (B, S, H, hd) -> (B*H, S, hd) with head-major flattening so that
     # q head b*H + h maps to kv head (b*H + h)//G == b*K + h//G.
     qf = q.transpose(0, 2, 1, 3).reshape(B * H, S, hd)

@@ -1,4 +1,4 @@
-"""jit'd public wrapper for the gw_cost kernel: padding + dispatch."""
+"""jit'd public wrapper for the gw_cost kernel: padding and its block."""
 from __future__ import annotations
 
 from typing import Optional
@@ -6,15 +6,15 @@ from typing import Optional
 from repro.kernels import dispatch
 from repro.kernels.gw_cost.gw_cost import gw_cost_pallas
 
-dispatch.register("gw_cost", default_block=32,
-                  description="grid GW cost assembly (4-D contraction)")
+# every axis of the 4-D contraction is tiled in blocks of BLOCK
+BLOCK = 32
 
 
 def gw_cost(A, B, T, loss: str = "l1", block: Optional[int] = None,
             interpret: Optional[bool] = None):
     """C[k,m] = Σ_{l,p} L(A[k,l], B[m,p]) T[l,p], padded + unpadded."""
     K, M = A.shape[0], B.shape[0]
-    block = dispatch.block_size("gw_cost", block)
+    block = block or BLOCK
     A_p, _ = dispatch.pad_to_multiple(A, (block, block))
     B_p, _ = dispatch.pad_to_multiple(B, (block, block))
     T_p, _ = dispatch.pad_to_multiple(T, (block, block))

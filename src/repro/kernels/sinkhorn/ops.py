@@ -7,13 +7,14 @@ from repro.core.sinkhorn import sinkhorn as sinkhorn_jnp
 from repro.kernels import dispatch
 from repro.kernels.sinkhorn.sinkhorn import sinkhorn_pallas
 
-dispatch.register("sinkhorn", default_block=0,
-                  description="VMEM-resident Sinkhorn scaling loop")
+# the kernel K stays resident in VMEM: the Pallas loop runs iff its
+# float32 bytes fit, else the jnp Sinkhorn does
+VMEM_BYTES = 8 * 2**20
 
 
 def sinkhorn(a, b, K, iters: int = 50, interpret: Optional[bool] = None):
     m, n = K.shape
-    if m * n * 4 <= dispatch.vmem_budget():
+    if m * n * 4 <= VMEM_BYTES:
         return sinkhorn_pallas(a, b, K, iters=iters,
                                interpret=dispatch.interpret_mode(interpret))
     return sinkhorn_jnp(a, b, K, iters)

@@ -5,7 +5,7 @@ same kernel matrix. On the grid support that matrix is a dense
 (s_r × s_c) block — small enough for VMEM — so the entire H-iteration
 loop runs with K resident on-chip: **zero HBM traffic inside the loop**
 (vs 2·H·s_r·s_c reads for the naive version; this is the memory-term
-optimization for the paper's own technique, cf. EXPERIMENTS.md §Perf).
+optimization for the paper's own technique).
 
 Single grid step; u/v iterates in VMEM scratch; matvecs hit the MXU via
 dot_general.

@@ -1,4 +1,4 @@
-"""Public wrappers + impl dispatch for the COO spar_cost kernel family.
+"""Public wrappers, impl dispatch and sizes for the COO spar_cost kernels.
 
 Three interchangeable implementations of the affine contract
 ``fn(t, off) = L-matvec(t) + off`` (see spar_cost.py):
@@ -34,22 +34,25 @@ from repro.kernels.spar_cost.spar_cost import (
     spar_matvec_pallas,
 )
 
-# the materialized matvec's blocks; the fused kernel's k-block is
-# ``K_BLOCK``
-dispatch.register("spar_cost", default_block=256,
-                  description="COO cost assembly (SPAR-GW hot path)")
+# The family's sizes. The fused kernel's k-block is ``K_BLOCK`` and its
+# l-block ``L_BLOCK`` (spar_cost.py); the materialized matvec tiles its
+# (s, s) matrix in square blocks of ``MATVEC_BLOCK``, capped at s.
+MATVEC_BLOCK = 256
+# HBM the resident (s, s) float32 loss matrix may take: "auto"
+# materializes iff 4·s² fits (s ≤ 11,585)
+MATERIALIZE_BYTES = 512 * 2**20
 
 
 def resolve_impl(impl: str, s: int) -> str:
     """Resolve ``"auto"`` to a concrete impl for a support of size s."""
     if impl != "auto":
         return impl
-    if s * s * 4 <= dispatch.materialize_budget():
+    if s * s * 4 <= MATERIALIZE_BYTES:
         return "materialized"
     return "pallas" if dispatch.backend() == "tpu" else "jnp"
 
 
-def _fused_setup(Cx, Cy, rows, cols, block: Optional[int]):
+def _fused_setup(Cx, Cy, rows, cols, block: Optional[int] = None):
     """The support padded for the fused kernel's l-walk (to ``L_BLOCK``),
     and its sublane-dense row panels over the support padded to the
     k-block (``block`` where given, else ``K_BLOCK``)."""
@@ -88,9 +91,11 @@ def spar_cost_fused(Cx, Cy, rows, cols, t, off=0.0, loss: str = "l2",
 
 def spar_matvec(Lmat, t, off=0.0, block: Optional[int] = None,
                 interpret: Optional[bool] = None):
-    """One-shot materialized-support matvec: Lmat @ t + off, (s,)."""
+    """One-shot materialized-support matvec: Lmat @ t + off, (s,).
+    ``block`` sets the square block (default: ``MATVEC_BLOCK``), capped
+    at s."""
     s = Lmat.shape[0]
-    b = dispatch.block_size("spar_cost", block, cap=s)
+    b = min(block or MATVEC_BLOCK, s)
     Lp, _ = dispatch.pad_to_multiple(Lmat, (b, b))
     s_p = Lp.shape[0]
     out = spar_matvec_pallas(Lp, _vec(t, s_p), _vec(off, s_p), bk=b, bl=b,
@@ -99,8 +104,7 @@ def spar_matvec(Lmat, t, off=0.0, block: Optional[int] = None,
 
 
 def make_spar_cost_fn(Cx, Cy, rows, cols, loss: str, impl: str = "auto",
-                      chunk: int = 1024, block: Optional[int] = None,
-                      interpret: Optional[bool] = None
+                      chunk: int = 1024, interpret: Optional[bool] = None
                       ) -> Callable[..., jnp.ndarray]:
     """Build ``fn(t, off=0.0) -> (s,) f32`` computing L-matvec(t) + off.
 
@@ -114,7 +118,7 @@ def make_spar_cost_fn(Cx, Cy, rows, cols, loss: str, impl: str = "auto",
     """
     with jax.named_scope("gw.cost"):
         fn = _cost_fn(Cx, Cy, rows, cols, loss, resolve_impl(
-            impl, rows.shape[0]), chunk, block, interpret)
+            impl, rows.shape[0]), chunk, interpret)
 
     def scoped(t, off=0.0):
         with jax.named_scope("gw.cost"):
@@ -123,7 +127,7 @@ def make_spar_cost_fn(Cx, Cy, rows, cols, loss: str, impl: str = "auto",
 
 
 def _cost_fn(Cx, Cy, rows, cols, loss: str, impl: str, chunk: int,
-             block: Optional[int], interpret: Optional[bool]):
+             interpret: Optional[bool]):
     """The unscoped closure of one resolved ``impl``."""
     s = rows.shape[0]
     if impl == "jnp":
@@ -132,7 +136,7 @@ def _cost_fn(Cx, Cy, rows, cols, loss: str, impl: str, chunk: int,
         return fn
 
     if impl == "pallas":
-        setup = _fused_setup(Cx, Cy, rows, cols, block)
+        setup = _fused_setup(Cx, Cy, rows, cols)
         itp = dispatch.interpret_mode(interpret)
 
         def fn(t, off=0.0):
@@ -143,7 +147,7 @@ def _cost_fn(Cx, Cy, rows, cols, loss: str, impl: str, chunk: int,
         # the gate bounds the resident s² matrix; the one-shot vectorized
         # gather additionally needs a ~3·s² transient (Gx, Gy, result) —
         # fall back to the O(chunk·s)-transient chunked build past that
-        direct_ok = 3 * s * s * 4 <= dispatch.materialize_budget()
+        direct_ok = 3 * s * s * 4 <= MATERIALIZE_BYTES
         Lmat = materialize_loss(Cx, Cy, rows, cols, loss,
                                 None if direct_ok else chunk)
         if dispatch.interpret_mode(interpret):
@@ -154,7 +158,7 @@ def _cost_fn(Cx, Cy, rows, cols, loss: str, impl: str, chunk: int,
             def fn(t, off=0.0):
                 return Lmat @ t.astype(jnp.float32) + off
             return fn
-        b = dispatch.block_size("spar_cost", block, cap=s)
+        b = min(MATVEC_BLOCK, s)
         Lp, _ = dispatch.pad_to_multiple(Lmat, (b, b))
         s_p = Lp.shape[0]
 

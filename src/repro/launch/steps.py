@@ -1,4 +1,4 @@
-"""jit-able step functions shared by the trainer, server, and dry-run."""
+"""jit-able step functions shared by the trainer and server."""
 from __future__ import annotations
 
 from functools import partial

@@ -24,11 +24,10 @@ import time
 # on CPU). Must be set before jax initializes.
 _LHS_FLAGS = ("--xla_tpu_enable_latency_hiding_scheduler=true "
               "--xla_tpu_megacore_fusion_allow_ags=true ")
-if "dryrun" not in os.environ.get("REPRO_MODE", ""):
-    os.environ.setdefault("XLA_FLAGS", "")
-    if "latency_hiding" not in os.environ["XLA_FLAGS"] \
-            and os.environ.get("REPRO_TPU"):
-        os.environ["XLA_FLAGS"] += " " + _LHS_FLAGS
+os.environ.setdefault("XLA_FLAGS", "")
+if "latency_hiding" not in os.environ["XLA_FLAGS"] \
+        and os.environ.get("REPRO_TPU"):
+    os.environ["XLA_FLAGS"] += " " + _LHS_FLAGS
 
 import jax
 import jax.numpy as jnp

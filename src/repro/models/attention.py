@@ -17,9 +17,7 @@ from repro.models.module import Builder
 
 NEG_INF = -1e30
 
-# Blockwise-attention KV chunk size. The dry-run's cost-compile mode sets
-# this to a huge value (single chunk) so XLA cost_analysis — which counts
-# scan bodies once, not x trip count — sees the full attention FLOPs.
+# Blockwise-attention KV chunk size (``set_flash_chunk`` resets it).
 _FLASH_CHUNK = {"size": 512}
 
 

@@ -3,8 +3,8 @@
 A model is ``n_superblocks`` scanned repetitions of a *superblock* (the
 arch's ``block_pattern``), plus optional tail blocks and an optional shared
 transformer block invoked once per superblock (Zamba2). Scan-over-layers
-keeps the HLO small (one superblock body compiled once) — essential for the
-512-device dry-run and for XLA's latency-hiding scheduler.
+keeps the HLO small (one superblock body compiled once) — essential for
+XLA's latency-hiding scheduler.
 
 Modes:
   · ``forward``      — teacher-forced training forward (no caches kept)
@@ -170,13 +170,8 @@ def superblock_apply(p, shared_p, cfg: ArchConfig, x, positions, caches,
 class Model:
     """Functional model wrapper for one architecture config."""
 
-    def __init__(self, cfg: ArchConfig, unroll_layers: bool = False):
+    def __init__(self, cfg: ArchConfig):
         self.cfg = cfg
-        # unroll_layers: replace the layer scan with a Python loop. Used by
-        # the dry-run's cost compiles — XLA cost_analysis counts loop bodies
-        # once (not x trip count), so FLOP/byte accounting needs an unrolled
-        # program. Production path keeps the scan (small HLO, fast compile).
-        self.unroll_layers = unroll_layers
 
     # -- parameters ---------------------------------------------------------
 
@@ -332,20 +327,7 @@ class Model:
         else:
             xs = (params["blocks"], caches["blocks"])
 
-        if self.unroll_layers:
-            n_sb = cfg.resolved_superblocks
-            carry = (x, jnp.float32(0.0))
-            outs = []
-            for i in range(n_sb):
-                xs_i = jax.tree.map(lambda a: a[i], xs)
-                carry, out_i = body(carry, xs_i)
-                outs.append(out_i)
-            x, aux = carry
-            scanned_caches = None if not want_cache else \
-                jax.tree.map(lambda *ys: jnp.stack(ys), *outs)
-        else:
-            (x, aux), scanned_caches = lax.scan(body, (x, jnp.float32(0.0)),
-                                                xs)
+        (x, aux), scanned_caches = lax.scan(body, (x, jnp.float32(0.0)), xs)
 
         new_tail = []
         if cfg.tail_blocks:

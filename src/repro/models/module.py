@@ -2,7 +2,7 @@
 
 A ``Builder`` interprets parameter declarations in one of three modes:
   · ``init``  — materialize arrays (CPU smoke tests, real training)
-  · ``shape`` — ShapeDtypeStructs only (dry-run: no allocation, 90B-safe)
+  · ``shape`` — ShapeDtypeStructs only (no allocation, 90B-safe)
   · ``axes``  — logical sharding axes tuples (fed to distrib.sharding rules)
 
 Module code declares each parameter exactly once; all three interpretations

@@ -1,6 +1,6 @@
 """Activation-sharding context shared by model components.
 
-Set by launch/dryrun/train under a mesh; no-op on single-device smoke tests.
+Set by launch/train under a mesh; no-op on single-device smoke tests.
 Constraints are divisibility-guarded so the same model code runs everywhere.
 """
 from __future__ import annotations
@@ -16,8 +16,7 @@ def set_activation_sharding(enabled: bool, dp=("data",), dp_size: int = 1,
     """``sp=True`` additionally shards the sequence dim of the residual
     stream over 'model' (sequence parallelism): per-layer activation saves
     under remat shrink by the TP degree; XLA inserts the SP all-gather at
-    layer entry (Korthikanti et al. pattern). Hillclimb lever — see
-    EXPERIMENTS.md §Perf."""
+    layer entry (Korthikanti et al. pattern)."""
     _ACT_SHARD.update(enabled=enabled, dp=tuple(dp), dp_size=dp_size,
                       model_size=model_size, sp=sp)
 

@@ -19,7 +19,7 @@ from repro.models.module import Builder
 # ---------------------------------------------------------------------------
 
 
-# Hillclimb lever (EXPERIMENTS.md §Perf): the fused in_proj output dim
+# Sharding option: the fused in_proj output dim
 # (2*d_in + 2N + H) is generally NOT divisible by the TP degree (zamba2:
 # 14563 % 16 != 0) -> the divisibility guard replicates the whole 208MB
 # parameter and its gradient all-reduces dominate. split_proj=True factors

@@ -3,8 +3,8 @@
 One :class:`MetricsRegistry` per process (the module-level default,
 reachable via :func:`registry`) that every subsystem registers into:
 ``ServeMetrics`` (request/batch/latency), ``GeometryCache`` (hit / miss /
-eviction), ``kernels/dispatch`` (per-family resolution counts, autotune
-winners) and the solve front door (per-status solve
+eviction), ``core/sinkhorn`` (the sparse log-domain layout each trace
+picks) and the solve front door (per-status solve
 counts, rescue and fallback totals). Two exporters read it:
 
 * :meth:`MetricsRegistry.snapshot` — a JSON-safe nested dict (the

@@ -1,4 +1,7 @@
 """Per-kernel shape/dtype sweeps vs the pure-jnp oracles (interpret mode)."""
+import sys
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -96,7 +99,11 @@ def test_ssd_intra_kernel_sweep(shape):
 # ---------------------------------------------------------------------------
 
 def test_roofline_peaks_by_device_kind():
-    from benchmarks.roofline import peaks
+    gwbench = str(Path(__file__).resolve().parents[1] / "benchmarks"
+                  / "gwbench")
+    if gwbench not in sys.path:
+        sys.path.insert(0, gwbench)
+    from peaks import peaks
     v5e = peaks("TPU v5 lite")
     assert (v5e.flops, v5e.hbm_bw) == (197e12, 819e9)
     for kind in ("cpu", "TPU v4", "unrecorded"):

@@ -1,5 +1,5 @@
 """Parity (interpret mode) + regression tests for the fused spar_cost
-kernel family, and for the unified kernels/dispatch.py layer."""
+kernel family, and for the kernels/dispatch.py layer."""
 import importlib
 
 import jax
@@ -9,6 +9,8 @@ import pytest
 
 from repro.core.spar_gw import spar_gw
 from repro.kernels import dispatch
+from repro.kernels.gw_cost.ops import gw_cost
+from repro.kernels.sinkhorn.ops import sinkhorn as sinkhorn_kernel
 from repro.kernels.spar_cost.ops import (
     make_spar_cost_fn,
     resolve_impl,
@@ -186,7 +188,7 @@ def test_make_spar_cost_fn_impls_agree():
     outs = {}
     for impl in ("jnp", "pallas", "materialized"):
         fn = make_spar_cost_fn(Cx, Cy, rows, cols, "l2", impl=impl,
-                               chunk=32, block=16)
+                               chunk=32)
         outs[impl] = np.array(fn(t, off))
     np.testing.assert_allclose(outs["pallas"], outs["jnp"], rtol=1e-5,
                                atol=1e-6)
@@ -245,48 +247,6 @@ def test_interpret_mode_env_override(monkeypatch):
     assert dispatch.interpret_mode(True) is True
 
 
-def test_block_size_resolution_order(monkeypatch):
-    dispatch.register("_test_family", default_block=64)
-    assert dispatch.block_size("_test_family") == 64
-    monkeypatch.setenv("REPRO_BLOCK__TEST_FAMILY", "16")
-    assert dispatch.block_size("_test_family") == 16
-    assert dispatch.block_size("_test_family", override=8) == 8
-    assert dispatch.block_size("_test_family", cap=4) == 4
-
-
-def test_autotune_caches_best_block(monkeypatch):
-    dispatch.register("_test_tune", default_block=128)
-    calls = []
-
-    def bench(block):
-        calls.append(block)
-        if block == 32:
-            import time
-            time.sleep(0.002)
-        return jnp.zeros(())
-
-    best = dispatch.autotune("_test_tune", [8, 32], bench, reps=1)
-    assert best == 8
-    monkeypatch.delenv("REPRO_BLOCK__TEST_TUNE", raising=False)
-    assert dispatch.block_size("_test_tune") == 8
-    recs = [r for r in dispatch.autotune_records()
-            if r["family"] == "_test_tune"]
-    assert recs and recs[-1]["best_block"] == 8
-
-
-def test_autotune_raises_when_every_candidate_fails():
-    dispatch.register("_test_tune_fail", default_block=128)
-
-    def bench(block):
-        raise ValueError(f"block {block} over budget")
-
-    with pytest.raises(RuntimeError, match="every candidate") as info:
-        dispatch.autotune("_test_tune_fail", [8, 32], bench, reps=1)
-    assert isinstance(info.value.__cause__, ValueError)
-    assert not [r for r in dispatch.autotune_records()
-                if r["family"] == "_test_tune_fail"]
-
-
 def test_pad_unpad_roundtrip():
     x = jnp.arange(12, dtype=jnp.float32).reshape(3, 4)
     xp, shape = dispatch.pad_to_multiple(x, (8, 128))
@@ -295,8 +255,39 @@ def test_pad_unpad_roundtrip():
                                   np.array(x))
 
 
-def test_resolve_impl_auto_gate(monkeypatch):
-    monkeypatch.setenv("REPRO_SPAR_MATERIALIZE_BUDGET", str(4 * 100 * 100))
-    assert resolve_impl("auto", 100) == "materialized"
-    assert resolve_impl("auto", 101) in ("pallas", "jnp")
+def test_resolve_impl_auto_gate():
+    # 4·s² ≤ 512 MiB holds up to s = 11,585
+    assert resolve_impl("auto", 11585) == "materialized"
+    assert resolve_impl("auto", 11586) in ("pallas", "jnp")
     assert resolve_impl("jnp", 10**9) == "jnp"
+
+
+def _jaxpr_text(fn, *shapes):
+    return str(jax.make_jaxpr(fn)(*(jnp.ones(sh) for sh in shapes)))
+
+
+# per variable the parent's kernels read for sizing: a hostile value, and
+# the reading of the entry point it reached
+_SIZING_READINGS = {
+    "REPRO_BLOCK_SPAR_COST": ("8", lambda: _jaxpr_text(
+        lambda L, t: spar_matvec(L, t, interpret=True), (64, 64), (64,))),
+    "REPRO_BLOCK_GW_COST": ("8", lambda: _jaxpr_text(
+        lambda A, B, T: gw_cost(A, B, T, interpret=True),
+        (64, 64), (64, 64), (64, 64))),
+    "REPRO_SPAR_MATERIALIZE_BUDGET": ("0", lambda: resolve_impl("auto",
+                                                                1000)),
+    "REPRO_VMEM_BUDGET": ("0", lambda: "pallas_call" in _jaxpr_text(
+        lambda a, b, K: sinkhorn_kernel(a, b, K, iters=1, interpret=True),
+        (1024,), (1024,), (1024, 1024))),
+}
+
+
+@pytest.mark.parametrize("var", sorted(_SIZING_READINGS))
+def test_kernel_sizing_reads_no_process_env(var, monkeypatch):
+    """Kernel sizes are the families' own constants: no process variable
+    changes the program a kernel entry point traces."""
+    hostile, read = _SIZING_READINGS[var]
+    monkeypatch.delenv(var, raising=False)
+    want = read()
+    monkeypatch.setenv(var, hostile)
+    assert read() == want
